@@ -133,7 +133,7 @@ except ModuleNotFoundError:  # invoked as a script: put the repo root on path
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     from benchmarks.common import Row, emit
 from repro.configs import SMOKE_CONFIGS
-from repro.launch import serve
+from repro.launch import compile_cache, serve
 from repro.models import get_model
 from repro.training import data as data_mod
 
@@ -1255,6 +1255,7 @@ def main(argv=None) -> int:
                          "perf-trajectory JSON (benchmarks/BENCH_serve.json "
                          "is the checked-in copy), then exit")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.chunked and not args.paged:
         ap.error("--chunked requires --paged")
     if args.profile_mix and not (args.paged and args.chunked):

@@ -155,8 +155,16 @@ def verify_step(model: Model, params, cache, tokens, t_new, lengths):
 # donation must be RE-declared here because an inlined jit's
 # donate_argnums are ignored. GSPMD derives the head-sharded attention
 # and column/row-sharded FFN partitioning from the committed param
-# shardings + these cache constraints — no shard_map, one executable per
-# geometry, findable in the same trace-audit registry as the rest.
+# shardings + these cache constraints — one executable per geometry,
+# findable in the same trace-audit registry as the rest. The inner step
+# is traced under the mesh (``_on_mesh``) so that the Pallas attention
+# kernels, which GSPMD cannot partition, run under shard_map on each
+# device's own heads (kernels/ops.py ``_on_own_heads``).
+
+
+def _on_mesh(shardings):
+    """Context that traces a TP step under its shardings' mesh."""
+    return jax.sharding.use_abstract_mesh(shardings[0][0].mesh.abstract_mesh)
 
 
 def _tp_constrain(tree, shardings):
@@ -186,8 +194,9 @@ def tp_prefill(model: Model, params, tokens, prompt_lengths, max_len,
     """``prefill`` on the mesh: the fresh row cache comes back committed
     to its TP shards (head axis), logits replicated for host-side
     sampling. No donation — prefill allocates its cache internally."""
-    logits, cache = prefill(model, params, tokens, prompt_lengths, max_len,
-                            extra)
+    with _on_mesh(row_shardings):
+        logits, cache = prefill(model, params, tokens, prompt_lengths,
+                                max_len, extra)
     return (_tp_replicated(logits, row_shardings),
             _tp_constrain(cache, row_shardings))
 
@@ -196,7 +205,8 @@ def tp_prefill(model: Model, params, tokens, prompt_lengths, max_len,
                    static_argnames=("shardings",), donate_argnums=(2,))
 def tp_decode_step(model: Model, params, cache, token, *, shardings):
     """``decode_step`` on the mesh (cache donated shard-for-shard)."""
-    logits, cache = decode_step(model, params, cache, token)
+    with _on_mesh(shardings):
+        logits, cache = decode_step(model, params, cache, token)
     return (_tp_replicated(logits, shardings),
             _tp_constrain(cache, shardings))
 
@@ -206,7 +216,9 @@ def tp_decode_step(model: Model, params, cache, token, *, shardings):
 def tp_mixed_step(model: Model, params, cache, tokens, t_new, lengths, *,
                   shardings):
     """``mixed_step`` on the mesh (cache donated shard-for-shard)."""
-    logits, cache = mixed_step(model, params, cache, tokens, t_new, lengths)
+    with _on_mesh(shardings):
+        logits, cache = mixed_step(model, params, cache, tokens, t_new,
+                                   lengths)
     return (_tp_replicated(logits, shardings),
             _tp_constrain(cache, shardings))
 
@@ -216,7 +228,9 @@ def tp_mixed_step(model: Model, params, cache, tokens, t_new, lengths, *,
 def tp_verify_step(model: Model, params, cache, tokens, t_new, lengths, *,
                    shardings):
     """``verify_step`` on the mesh (cache donated shard-for-shard)."""
-    logits, cache = verify_step(model, params, cache, tokens, t_new, lengths)
+    with _on_mesh(shardings):
+        logits, cache = verify_step(model, params, cache, tokens, t_new,
+                                    lengths)
     return (_tp_replicated(logits, shardings),
             _tp_constrain(cache, shardings))
 

@@ -155,9 +155,10 @@ def tp_draft_window(
     Donation is re-declared because the inlined inner jit's is ignored."""
     from repro.core import engine
 
-    window, cache = draft_window(
-        model, exit_layer, n_draft, params, cache, token0, n_live, lengths
-    )
+    with engine._on_mesh(shardings):
+        window, cache = draft_window(
+            model, exit_layer, n_draft, params, cache, token0, n_live, lengths
+        )
     return (engine._tp_replicated(window, shardings),
             engine._tp_constrain(cache, shardings))
 

@@ -28,6 +28,7 @@ from __future__ import annotations
 import heapq
 from typing import Any, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -450,9 +451,15 @@ class BlockPool(_PoolBase):
     @hot_path
     def sync(self) -> None:
         """Ship the host block table to the device if it changed since the
-        last decode step (one tiny [slots, max_blocks] int32 transfer)."""
+        last decode step (one tiny [slots, max_blocks] int32 transfer),
+        committed where the old table was: a step keys its compile on
+        whether its inputs are committed, so the table keeps its placement
+        whether or not it changed."""
         if self._bt_dirty:
-            self.cache["block_tables"] = jnp.asarray(self.block_tables)
+            old = self.cache["block_tables"]
+            self.cache["block_tables"] = (
+                jax.device_put(self.block_tables, old.sharding)
+                if old.committed else jnp.asarray(self.block_tables))
             self._bt_dirty = False
 
     def reset(self) -> None:

@@ -1,80 +1,28 @@
-"""Pallas TPU flash-decode kernel: one new token per sequence against a
-long static KV cache (the memory-bound phase, paper Obs #1/#3).
+"""Pallas TPU flash-decode: one new token per sequence against a long
+static KV cache (the memory-bound phase, paper Obs #1/#3).
 
 Decode attention is pure HBM streaming: arithmetic intensity ≈ 2 FLOPs per
 cached byte, far below the v5e ridge — the kernel's job is to keep HBM→VMEM
-transfers saturated, not the MXU. Design:
+transfers saturated, not the MXU. It is the ``Tq = 1`` case of the flash
+kernel (kernels/flash_attention.py):
 
-- grid (B, Hkv, S/block_k): the innermost KV-block dimension streams the
-  cache once; the running online-softmax state (m, l, acc) for the q-head
-  group sits in VMEM scratch;
-- GQA: the q-head group [G, D] for one KV head rides in VMEM the whole
-  time; each KV tile is read exactly once (minimum possible traffic);
+- grid (B, 1, S/block_k): the innermost KV-block dimension streams the
+  cache once, all KV heads per tile, in the cache's own layout; the
+  running online-softmax state for every q-head group sits in VMEM
+  scratch;
+- GQA: each KV head's [G, D] q-head group is one row block, so each KV
+  tile is read exactly once (minimum possible traffic);
 - per-sequence ``lengths`` mask validity (static cache, paper §4.1.2);
-  tiles entirely past ``lengths`` are skipped via predication — with the
-  LSE-combine in kernels/ops.py this same partial structure serves as the
-  shard_map sequence-parallel decode path.
+  tiles entirely past ``lengths`` are skipped via predication on
+  scalar-prefetched tile bounds.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-NEG_INF = -1e30
-
-
-def _decode_kernel(
-    length_ref,  # [1] int32 (SMEM-ish scalar per batch row)
-    q_ref,  # [1, 1, G, D]
-    k_ref,  # [1, bk, 1, D]
-    v_ref,  # [1, bk, 1, Dv]
-    o_ref,  # [1, 1, G, Dv]
-    m_scr, l_scr, acc_scr,  # [G], [G], [G, Dv]
-    *,
-    scale: float,
-    block_k: int,
-    n_k_blocks: int,
-):
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = length_ref[0]
-    base = ik * block_k
-    valid = base + jax.lax.iota(jnp.int32, block_k) < length  # [bk]
-
-    @pl.when(base < length)
-    def _compute():  # skip tiles entirely past the live cache
-        q = q_ref[0, 0, 0].astype(jnp.float32) * scale  # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)  # [bk, Dv]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [G, bk]
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(valid[None, :], p, 0.0)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
-
-    @pl.when(ik == n_k_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+from repro.kernels.flash_attention import flash_attention_pallas
 
 
 def decode_attention_pallas(
@@ -87,43 +35,18 @@ def decode_attention_pallas(
     block_k: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, hq, d = q.shape
-    s, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
-    g = hq // hkv
-    scale = scale if scale is not None else d ** -0.5
-    block_k = min(block_k, s)
-    pk = (-s) % block_k
-    if pk:
-        k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    sp = s + pk
-    n_k_blocks = sp // block_k
-    qg = q.reshape(b, 1, hkv, g, d)
+    s = k.shape[1]
     lengths = lengths.astype(jnp.int32)
-
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, block_k=block_k, n_k_blocks=n_k_blocks
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, hkv, n_k_blocks),
-        in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, ik: (ib,)),
-            pl.BlockSpec((1, 1, 1, g, d), lambda ib, ih, ik: (ib, 0, ih, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda ib, ih, ik: (ib, ik, ih, 0)),
-            pl.BlockSpec((1, block_k, 1, dv), lambda ib, ih, ik: (ib, ik, ih, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, g, dv), lambda ib, ih, ik: (ib, 0, ih, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, 1, hkv, g, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
-        ],
+    kpos = jnp.arange(s, dtype=jnp.int32)[None]
+    out = flash_attention_pallas(
+        q[:, None], k, v,
+        q_positions=(lengths - 1)[:, None],
+        k_positions=kpos,
+        causal=False,
+        k_valid=kpos < lengths[:, None],
+        scale=scale,
+        block_q=1,
+        block_k=block_k,
         interpret=interpret,
-    )(lengths, qg, k, v)
-    return out.reshape(b, hq, dv)
+    )
+    return out[:, 0]

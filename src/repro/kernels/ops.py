@@ -21,16 +21,51 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as _ref
 
 
+def _interpret() -> bool:
+    """Pallas kernels compile through Mosaic on a TPU backend and run in
+    the Pallas interpreter everywhere else."""
+    return jax.default_backend() != "tpu"
+
+
 def _default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return "xla" if _interpret() else "pallas"
 
 
 def _resolve(impl: str) -> str:
     return _default_impl() if impl == "auto" else impl
+
+
+def _on_own_heads(kernel, q_head_axis: int, q, k, v, *rest):
+    """Call a Pallas attention kernel on this device's heads.
+
+    GSPMD cannot partition a Mosaic kernel. A step traced under a mesh with
+    a ``"model"`` axis (the tensor-parallel pool shards heads over it, see
+    distributed/tp_pool.py) runs the kernel under ``shard_map``: q, k, v
+    and the output split on their head axis, ``rest`` (positions,
+    validity, lengths) replicated. Where the KV heads do not divide the
+    axis (the pool then shards the cache's sequence axis instead), every
+    device runs the whole kernel on gathered inputs. Off such a mesh the
+    kernel is called as is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    n = 1 if mesh.empty else dict(mesh.shape).get("model", 1)
+    if n == 1:
+        return kernel(q, k, v, *rest)
+    split = "model" if k.shape[2] % n == 0 else None
+
+    def heads(ndim, axis):
+        return P(*(split if i == axis else None for i in range(ndim)))
+
+    q_spec, kv_spec = heads(q.ndim, q_head_axis), heads(k.ndim, 2)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(q_spec, kv_spec, kv_spec) + (P(),) * len(rest),
+        out_specs=q_spec, check_vma=False,
+    )(q, k, v, *rest)
 
 
 NEG_INF = -1e30  # finite sentinel: keeps online softmax NaN-free
@@ -79,11 +114,15 @@ def flash_attention(
     if impl == "pallas":
         from repro.kernels import flash_attention as _fa
 
-        return _fa.flash_attention_pallas(
-            q, k, v, q_positions=q_positions, k_positions=k_positions,
-            causal=causal, window=window, k_valid=k_valid, scale=scale,
-            block_q=block_q, block_k=block_k,
-            interpret=jax.default_backend() != "tpu",
+        def kernel(q, k, v, q_positions, k_positions, k_valid):
+            return _fa.flash_attention_pallas(
+                q, k, v, q_positions=q_positions, k_positions=k_positions,
+                causal=causal, window=window, k_valid=k_valid, scale=scale,
+                block_q=block_q, block_k=block_k, interpret=_interpret(),
+            )
+
+        return _on_own_heads(
+            kernel, 2, q, k, v, q_positions, k_positions, k_valid
         )
     if impl == "xla_blockskip":
         return _flash_xla_blockskip(
@@ -306,10 +345,13 @@ def decode_attention(
     if impl == "pallas":
         from repro.kernels import decode_attention as _da
 
-        return _da.decode_attention_pallas(
-            q, k, v, lengths, scale=scale, block_k=block_k,
-            interpret=jax.default_backend() != "tpu",
-        )
+        def kernel(q, k, v, lengths):
+            return _da.decode_attention_pallas(
+                q, k, v, lengths, scale=scale, block_k=block_k,
+                interpret=_interpret(),
+            )
+
+        return _on_own_heads(kernel, 1, q, k, v, lengths)
     b, s, hkv, d = k.shape
     k_valid = jnp.arange(s)[None, :] < lengths[:, None]
     kpos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
@@ -370,7 +412,7 @@ def rmsnorm(x, weight, eps: float = 1e-5, impl: str = "auto"):
         from repro.kernels import rmsnorm as _rn
 
         return _rn.rmsnorm_pallas(
-            x, weight, eps=eps, interpret=jax.default_backend() != "tpu"
+            x, weight, eps=eps, interpret=_interpret()
         )
     return _ref.rmsnorm_ref(x, weight, eps)
 
@@ -404,7 +446,7 @@ def int8_matmul_weight_only(x, w_q, w_scale, impl: str = "auto"):
         from repro.kernels import int8_matmul as _im
 
         return _im.int8_matmul_pallas(
-            x, w_q, w_scale, interpret=jax.default_backend() != "tpu"
+            x, w_q, w_scale, interpret=_interpret()
         )
     if impl == "ref":
         return _ref.int8_matmul_ref(x, w_q, w_scale)
@@ -427,7 +469,7 @@ def int8_matmul_dynamic(x, w_q, w_scale, impl: str = "auto"):
         from repro.kernels import int8_matmul as _im
 
         return _im.int8_matmul_dynamic_pallas(
-            x_q, w_q, w_scale, x_scale, interpret=jax.default_backend() != "tpu"
+            x_q, w_q, w_scale, x_scale, interpret=_interpret()
         )
     out = _ref.int8_matmul_ref(x_q, w_q, w_scale, x_scale=x_scale)
     return out.astype(x.dtype)
@@ -457,7 +499,7 @@ def ssd_scan(
 
         return _ss.ssd_scan_pallas(
             x, dt, A, B_, C, D, chunk=chunk, initial_state=initial_state,
-            interpret=jax.default_backend() != "tpu",
+            interpret=_interpret(),
         )
     return _ssd_chunked_xla(x, dt, A, B_, C, D, chunk, initial_state)
 
@@ -572,7 +614,7 @@ def hstu_attention(
 
         return _ha.hstu_attention_pallas(
             q, k, v, rel_bias, max_attn_len=max_attn_len, lengths=lengths,
-            interpret=jax.default_backend() != "tpu",
+            interpret=_interpret(),
         )
     return _ref.hstu_attention_ref(
         q, k, v, rel_bias, max_attn_len=max_attn_len, lengths=lengths

@@ -68,6 +68,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.core import engine, profiles, sampling
 from repro.core.scheduler import Scheduler, ServeRequest
+from repro.launch import compile_cache
 from repro.models import get_model
 from repro.training import data as data_mod
 
@@ -658,7 +659,8 @@ def warmup(model, params, *, slots: int, pad_to: int, max_new_cap: int,
            prefix_cache: bool = False,
            profile_mix: bool = False, n_beams: int = 2,
            speculative: bool = False, exit_layer: int = 1,
-           n_draft: int = 4, tp: Optional[int] = None) -> None:
+           n_draft: int = 4, tp: Optional[int] = None,
+           replicas: Optional[int] = None) -> None:
     """Compile the serving executables (single-slot prefill, pool decode
     step, slot scatter — plus block copy/length scatter when paged, plus
     the mixed step when chunked) before any timed run. ``profile_mix``
@@ -668,50 +670,69 @@ def warmup(model, params, *, slots: int, pad_to: int, max_new_cap: int,
     (exit_layer, n_draft) geometry. ``prefix_cache`` warms block
     adoption (``kv_cache.set_slot_length`` at the adopt signature) by
     serving a prompt twice — the replay hits the trie. ``tp`` warms the
-    sharded TP step family on its own mesh instead."""
-    tp_mesh = None
-    if tp is not None and tp > 1:
-        from repro.distributed import tp_pool
+    sharded TP step family on its own mesh instead. ``replicas`` warms
+    every replica of the router ``run_scheduler(replicas=...)`` builds:
+    an executable is compiled per device or submesh, so each replica's
+    placement needs its own warm run."""
+    if replicas is not None:
+        from repro.core.router import ReplicaRouter
 
-        tp_mesh = tp_pool.make_tp_mesh(tp)
-    sched = Scheduler(
-        model, params, slots=slots, pad_to=pad_to, max_new_cap=max_new_cap,
-        paged=paged, block_size=block_size, num_blocks=num_blocks,
-        chunked=chunked, prefill_budget=prefill_budget,
-        prefix_cache=prefix_cache, tp_mesh=tp_mesh,
-    )
-    rng = np.random.default_rng(0)
-    full_prompt = rng.integers(0, 8, size=pad_to)
-    reqs = [
-        ServeRequest(rid=0, prompt=full_prompt, max_new=2),
-        ServeRequest(rid=1, prompt=rng.integers(0, 8, size=3), max_new=2),
-    ]
-    if profile_mix and slots >= max(n_beams, 2):
-        reqs.append(ServeRequest(
-            rid=2, prompt=rng.integers(0, 8, size=3), max_new=2,
-            profile=profiles.BeamProfile(n_beams=n_beams, eos_id=2),
-        ))
-        reqs.append(ServeRequest(
-            rid=3, prompt=rng.integers(0, 8, size=3), max_new=2,
-            profile=profiles.ContrastiveProfile(uncond_token=0),
-        ))
-    if speculative:
-        # max_new > n_draft + 1 so the warm run takes at least one full
-        # draft+verify step at the serving window geometry
-        reqs.append(ServeRequest(
-            rid=4, prompt=rng.integers(0, 8, size=3),
-            max_new=min(n_draft + 2, max_new_cap),
-            profile=profiles.SpeculativeProfile(
-                exit_layer=exit_layer, n_draft=n_draft,
-            ),
-        ))
-    sched.run(reqs)
-    if prefix_cache:
-        # rid 0's prompt blocks are in the trie now; its twin ADOPTS them
-        sched.run([ServeRequest(rid=5, prompt=full_prompt, max_new=2)])
+        scheds = ReplicaRouter(
+            model, params, replicas=replicas, slots=slots, pad_to=pad_to,
+            max_new_cap=max_new_cap, paged=paged, block_size=block_size,
+            num_blocks=num_blocks, chunked=chunked,
+            prefill_budget=prefill_budget, prefix_cache=prefix_cache, tp=tp,
+        ).replicas
+    else:
+        tp_mesh = None
+        if tp is not None and tp > 1:
+            from repro.distributed import tp_pool
+
+            tp_mesh = tp_pool.make_tp_mesh(tp)
+        scheds = [Scheduler(
+            model, params, slots=slots, pad_to=pad_to,
+            max_new_cap=max_new_cap, paged=paged, block_size=block_size,
+            num_blocks=num_blocks, chunked=chunked,
+            prefill_budget=prefill_budget, prefix_cache=prefix_cache,
+            tp_mesh=tp_mesh,
+        )]
+    for sched in scheds:
+        rng = np.random.default_rng(0)
+        full_prompt = rng.integers(0, 8, size=pad_to)
+        reqs = [
+            ServeRequest(rid=0, prompt=full_prompt, max_new=2),
+            ServeRequest(rid=1, prompt=rng.integers(0, 8, size=3), max_new=2),
+        ]
+        if profile_mix and slots >= max(n_beams, 2):
+            reqs.append(ServeRequest(
+                rid=2, prompt=rng.integers(0, 8, size=3), max_new=2,
+                profile=profiles.BeamProfile(n_beams=n_beams, eos_id=2),
+            ))
+            reqs.append(ServeRequest(
+                rid=3, prompt=rng.integers(0, 8, size=3), max_new=2,
+                profile=profiles.ContrastiveProfile(uncond_token=0),
+            ))
+        if speculative:
+            # max_new > n_draft + 1 so the warm run takes at least one full
+            # draft+verify step at the serving window geometry
+            reqs.append(ServeRequest(
+                rid=4, prompt=rng.integers(0, 8, size=3),
+                max_new=min(n_draft + 2, max_new_cap),
+                profile=profiles.SpeculativeProfile(
+                    exit_layer=exit_layer, n_draft=n_draft,
+                ),
+            ))
+        sched.run(reqs)
+        if prefix_cache:
+            # rid 0's prompt blocks are in the trie now; its twin ADOPTS them
+            sched.run([ServeRequest(rid=5, prompt=full_prompt, max_new=2)])
 
 
-def main(argv=None):
+def main(argv=None, *, return_requests: bool = False):
+    """Serve one generated trace and print its metrics; returns the metrics
+    dict (with ``return_requests``, also the finished requests, as
+    :func:`run_scheduler` does)."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -809,6 +830,9 @@ def main(argv=None):
         ap.error("--mix-classes is its own trace generator; drop "
                  "--profile-mix / --shared-prefix")
 
+    dev = jax.devices()[0]
+    print(f"[serve] platform={dev.platform} device_kind={dev.device_kind} "
+          f"device_count={len(jax.devices())}")
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -870,6 +894,7 @@ def main(argv=None):
     has_groups = bool(args.profile_mix) or bool(
         {"beam", "cfg", "contrastive"} & set(mix_class_kinds)
     )
+    t_warm = time.perf_counter()
     warmup(model, params, slots=args.batch_slots, pad_to=pad_to,
            max_new_cap=args.max_new, paged=args.paged,
            block_size=args.block_size, num_blocks=args.num_blocks,
@@ -877,8 +902,10 @@ def main(argv=None):
            profile_mix=has_groups, n_beams=args.n_beams,
            speculative=("speculative" in mix_kinds
                         or "speculative" in mix_class_kinds),
-           exit_layer=args.exit_layer, n_draft=args.n_draft, tp=args.tp)
-    m = run_scheduler(
+           exit_layer=args.exit_layer, n_draft=args.n_draft, tp=args.tp,
+           replicas=args.replicas)
+    warmup_s = time.perf_counter() - t_warm
+    m, done = run_scheduler(
         model, params, reqs, slots=args.batch_slots, pad_to=pad_to,
         max_new_cap=args.max_new, eos_id=args.eos_id, policy=args.policy,
         paged=args.paged, block_size=args.block_size,
@@ -886,8 +913,11 @@ def main(argv=None):
         prefill_budget=args.prefill_budget,
         prefix_cache=args.prefix_cache,
         priority_boost_after=args.boost_after, seed=args.seed,
-        replicas=args.replicas, tp=args.tp,
+        replicas=args.replicas, tp=args.tp, return_requests=True,
     )
+    # compiling every serving executable is warm-up's cost; the timed run
+    # above then replays compiled programs only
+    m["warmup_s"] = warmup_s
     mode = args.policy + ("/paged" if args.paged else "") + (
         "/chunked" if args.chunked else "") + (
         "/pfx" if args.prefix_cache else "") + (
@@ -958,6 +988,8 @@ def main(argv=None):
                   f"occ={e['mean_slot_occupancy']:.2f} | "
                   f"ttft p50={e['ttft_p50_ms']:.0f}ms | "
                   f"tpot p50={e['tpot_p50_ms']:.1f}ms")
+    if return_requests:
+        return m, done
     return m
 
 

@@ -255,13 +255,11 @@ def _sp_decode(cache, k_new, v_new, q, lengths):
         out = ops.combine_partial_attention(accs, ms, ls)
         return out.astype(qv.dtype), ck2, cv2
 
-    from jax.experimental.shard_map import shard_map
-
-    out, ck2, cv2 = shard_map(
+    out, ck2, cv2 = jax.shard_map(
         local, mesh=mesh,
         in_specs=(kv_spec, kv_spec, vec_spec, vec_spec, vec_spec, P(bspec)),
         out_specs=(vec_spec, kv_spec, kv_spec),
-        check_rep=False,
+        check_vma=False,
     )(cache["k"], cache["v"], k_new, v_new, q, lengths)
     return out, {"k": ck2, "v": cv2}
 

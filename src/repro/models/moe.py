@@ -235,10 +235,8 @@ def moe_ffn_ep(cfg: ModelConfig, p, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.nd
             out = out + L.ffn(pl["shared"], xf)
         return out.reshape(b_loc, t, d), aux
 
-    from jax.experimental.shard_map import shard_map
-
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh, in_specs=(pspec, xspec),
-        out_specs=(xspec, P()), check_rep=False,
+        out_specs=(xspec, P()), check_vma=False,
     )(p, x)
     return out, aux

@@ -16,6 +16,10 @@ the accounting invariants: no request lost or double-served, free
 slot/block conservation per replica against a dense mirror, and fully
 freed pools at drain.
 """
+import os
+import subprocess
+import sys
+
 import jax
 import numpy as np
 import pytest
@@ -33,6 +37,7 @@ PAD_TO = 8
 MAX_NEW_CAP = 16
 SLOTS = 2
 BLOCK_SIZE = 4
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -351,3 +356,53 @@ def test_serve_metrics_per_class_breakdown(llama):
     assert len(m["per_replica"]) == 2
     for rep in m["per_replica"]:
         assert rep["busy_s"] > 0 or rep["n_requests"] == 0
+
+
+# --------------------------------------------------------------------------
+# warm-up covers every replica: 2 forced host devices, one replica each
+# --------------------------------------------------------------------------
+
+_WARMUP_SCRIPT = """
+import jax
+assert jax.device_count() == 2, jax.device_count()
+from repro.analysis import trace_audit
+from repro.configs import SMOKE_CONFIGS
+from repro.launch import serve
+from repro.models import get_model
+from repro.training import data as data_mod
+
+cfg = SMOKE_CONFIGS["llama3.2-1b"].replace(dtype="float32")
+model = get_model(cfg)
+params = model.init(jax.random.PRNGKey(0))
+fns = trace_audit.serving_jits()
+for geometry in (dict(), dict(paged=True, block_size=4, chunked=True,
+                              prefill_budget=4)):
+    geometry.update(slots=2, pad_to=8, max_new_cap=8)
+    serve.warmup(model, params, replicas=2, **geometry)
+    warm = {name: fn._cache_size() for name, fn in fns.items()}
+    reqs = serve.poisson_trace(
+        data_mod.PAPER_PROFILES["seamless_s2t"], 4, pad_to=8, max_new_cap=8,
+        vocab_size=cfg.vocab_size, arrival_rate=0.0, seed=0)
+    m = serve.run_scheduler(model, params, reqs, replicas=2, **geometry)
+    assert all(rep["n_requests"] for rep in m["per_replica"]), m
+    grew = {name: (warm[name], fn._cache_size()) for name, fn in fns.items()
+            if fn._cache_size() != warm[name]}
+    assert not grew, f"compiled in the served run: {grew}"
+print("WARMUP_ALL_REPLICAS_OK")
+"""
+
+
+def test_warmup_compiles_every_replica():
+    """``serve.warmup(replicas=N)`` compiles each replica's executables on
+    that replica's own device, so the served run on a 2-device host
+    compiles nothing, on the contiguous and the paged+chunked pool."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    r = subprocess.run(
+        [sys.executable, "-c", _WARMUP_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "WARMUP_ALL_REPLICAS_OK" in r.stdout
