@@ -42,7 +42,7 @@ round dispatches ``step_begin`` on EVERY live replica before calling any
 when replicas sit on different devices (``distributed.sharding
 .replica_devices`` pins each replica's params + cache to its own device;
 on a single-device host they time-share it). Throughput accounting uses
-each replica's ``busy_s`` (wall seconds of its own admissions + steps):
+each replica's ``busy_s`` (host wall time of its admissions and steps):
 ``total tokens / max-over-replicas busy_s`` is the fleet's aggregate
 service rate — the wall a real one-device-per-replica deployment would
 take — and is what ``bench_serve --replicas`` gates near-linear scaling
@@ -343,7 +343,8 @@ class ReplicaRouter:
     def busy_max_s(self) -> float:
         """The fleet's emulated wall: replicas run concurrently on real
         (one-device-per-replica) deployments, so the slowest replica's
-        device-busy seconds bound the fleet's finish time."""
+        host wall time of its admissions and steps bounds the fleet's
+        finish time."""
         return max(s.busy_s for s in self.replicas)
 
     @property

@@ -117,14 +117,26 @@ with back-pressure), ``drain_waiting`` (replica-level preemption bounces
 requests back to the SHARED queue front), ``drain_finished``, and the
 two-phase ``step_begin``/``step_finish`` split (dispatch every replica's
 device work before syncing any — JAX async dispatch overlaps replicas on
-multi-device hosts). ``busy_s`` accumulates the wall seconds this
-replica's device work + admissions actually took, which is the
-device-busy denominator the router's aggregate-throughput accounting
-uses. Because every committed token is sampled under pure per-(rid,
-stream, token-index) keys folded from a SHARED ``base_key``, a request's
-output is bit-identical no matter which replica serves it, how often it
-is preempted, or who its batch mates are — replica placement is a pure
-scheduling decision, never a numerics decision.
+multi-device hosts). ``busy_s`` is the host wall time of this replica's
+admissions and steps (read from its spans, below), the denominator the
+router's aggregate-throughput accounting uses. Because every committed
+token is sampled under pure per-(rid, stream, token-index) keys folded
+from a SHARED ``base_key``, a request's output is bit-identical no matter
+which replica serves it, how often it is preempted, or who its batch
+mates are — replica placement is a pure scheduling decision, never a
+numerics decision.
+
+**Host spans** (core/tracing.py): every phase of the host step runs
+inside a named span (``self.spans``) that lands in a profiler trace as a
+``TraceAnnotation`` and in per-name totals (``spans.totals()``).
+``sched.step`` (an XProf step) holds ``sched.begin`` (children
+``sched.blocks``: block growth and the block-table upload;
+``sched.launch``: the jitted step call) and ``sched.finish`` (children
+``sched.sync``: the step's device_get; ``sched.commit``; ``sched.groups``,
+one ``sched.group`` per resident group with ``sched.group.profile`` and
+``sched.group.sync``). ``sched.admit`` wraps an admission, with
+``sched.prefill`` around its prefill dispatch. Every host<->device round
+trip is a span named ``*.sync``.
 """
 from __future__ import annotations
 
@@ -143,6 +155,7 @@ from repro.core import engine, kv_cache, layerskip, profiles, sampling
 from repro.core.prefill import ChunkCursor, ChunkedPrefill
 from repro.core.prefix_cache import PrefixCache
 from repro.core.slot_pool import BlockPool, SlotPool
+from repro.core.tracing import Spans
 from repro.models.registry import Model
 
 
@@ -443,12 +456,7 @@ class Scheduler:
         self.occupancy_trace: List[float] = []
         self.block_occupancy_trace: List[float] = []
         self.peak_used_blocks = 0
-        # wall seconds this replica's own work took (steps + router-driven
-        # admissions): the device-busy denominator of the router's
-        # aggregate-throughput accounting — on a one-device host replicas
-        # time-share the device, so max-over-replicas busy_s is the wall a
-        # real fleet (one device per replica) would take
-        self.busy_s = 0.0
+        self.spans = Spans(clock)  # host spans of this replica (busy_s)
         self._seq = 0  # admission order (preemption picks the youngest)
         self._t0 = self.clock()  # run() rebases; timestamps are offsets
 
@@ -569,10 +577,11 @@ class Scheduler:
         assert slot is not None
         tokens, length = self._pad_prompt(req.prompt)
         n_prompt = int(length[0])
-        logits, row = self._steps.prefill(
-            self.model, self.params, tokens, length, self.max_len,
-            self._request_extra(req),
-        )
+        with self.spans("sched.prefill"):
+            logits, row = self._steps.prefill(
+                self.model, self.params, tokens, length, self.max_len,
+                self._request_extra(req),
+            )
         if self.paged:
             # dense assign pops the free-list directly: reclaim any
             # shortfall from the prefix cache's LRU list first (the
@@ -588,18 +597,18 @@ class Scheduler:
             self.pool.ensure(slot, n_prompt)
         self.n_prefills += 1
         if req.temperature <= 0.0:  # greedy: skip the top-p pipeline
-            first = int(sampling.greedy(logits)[0])
+            tok = sampling.greedy(logits)
         else:
             keys = sampling.slot_step_keys(
                 self.base_key, jnp.asarray([req.rid]), jnp.asarray([0])
             )
-            first = int(
-                sampling.sample_slots(
-                    logits, keys,
-                    jnp.asarray([req.temperature], jnp.float32),
-                    jnp.asarray([req.top_p], jnp.float32),
-                )[0]
+            tok = sampling.sample_slots(
+                logits, keys,
+                jnp.asarray([req.temperature], jnp.float32),
+                jnp.asarray([req.top_p], jnp.float32),
             )
+        with self.spans("sched.admit.sync"):
+            first = int(jax.device_get(tok)[0])
         req.t_admit, req.t_first = now, self._now()
         req.tokens.append(first)
         req.t_tokens.append(req.t_first)
@@ -675,9 +684,11 @@ class Scheduler:
         extra = self._request_extra(req)
         if prof.prefix_shared:
             tokens, length = self._pad_prompt(prompts[0])
-            logits, row = self._steps.prefill(
-                self.model, self.params, tokens, length, self.max_len, extra
-            )
+            with self.spans("sched.prefill"):
+                logits, row = self._steps.prefill(
+                    self.model, self.params, tokens, length, self.max_len,
+                    extra,
+                )
             self.n_prefills += 1
             self.pool.assign(slots[0], row, n_prompt)
             for s in slots[1:]:
@@ -690,10 +701,11 @@ class Scheduler:
             rows = []
             for s, p in zip(slots, prompts):
                 tokens, length = self._pad_prompt(p)
-                logits, row = self._steps.prefill(
-                    self.model, self.params, tokens, length, self.max_len,
-                    extra,
-                )
+                with self.spans("sched.prefill"):
+                    logits, row = self._steps.prefill(
+                        self.model, self.params, tokens, length,
+                        self.max_len, extra,
+                    )
                 self.n_prefills += 1
                 self.pool.assign(s, row, n_prompt)
                 rows.append(logits)
@@ -777,14 +789,20 @@ class Scheduler:
             if cand is None or not self._admissible(cand):
                 return
             del self.waiting[i]
-            if profiles.n_streams_of(cand.profile) > 1:
-                self._admit_one_group(cand, now)
-            elif self.chunked and not cand.extra_inputs:
+            self._admit_request(cand, now)
+
+    def _admit_request(self, req: ServeRequest, now: float) -> None:
+        """Admit ``req`` (already gated by ``_admissible``) down its path:
+        a slot group, a chunk cursor, or a dense prefill."""
+        with self.spans("sched.admit", rid=req.rid):
+            if profiles.n_streams_of(req.profile) > 1:
+                self._admit_one_group(req, now)
+            elif self.chunked and not req.extra_inputs:
                 # extra-input requests need the prefill program (the chunk
                 # path streams tokens only), so they take the dense path
-                self._admit_one_chunked(cand, now)
+                self._admit_one_chunked(req, now)
             else:
-                self._admit_one(cand, now)
+                self._admit_one(req, now)
 
     # ---- router hooks (core/router.py) -----------------------------------
     @property
@@ -814,15 +832,20 @@ class Scheduler:
         this replica's own work, so it lands in ``busy_s``."""
         if not self._admissible(req):
             return False
-        t = self.clock()
-        if profiles.n_streams_of(req.profile) > 1:
-            self._admit_one_group(req, now)
-        elif self.chunked and not req.extra_inputs:
-            self._admit_one_chunked(req, now)
-        else:
-            self._admit_one(req, now)
-        self.busy_s += self.clock() - t
+        self._admit_request(req, now)
         return True
+
+    @property
+    def busy_s(self) -> float:
+        """Host wall time of this replica's admissions and steps (its
+        ``sched.admit``, ``sched.begin`` and ``sched.finish`` spans): the
+        denominator of the router's aggregate-throughput accounting. On a
+        one-device host replicas time-share the device, so
+        max-over-replicas ``busy_s`` is the wall a real fleet (one device
+        per replica) would take."""
+        t = self.spans.totals()
+        return sum(t[n]["seconds"] for n in
+                   ("sched.admit", "sched.begin", "sched.finish") if n in t)
 
     def drain_waiting(self) -> List[ServeRequest]:
         """Reclaim requests this replica preempted back to ITS queue front
@@ -1008,16 +1031,17 @@ class Scheduler:
         (``device_get``, not ``np.asarray`` — explicit, and batching-
         friendly if more per-step outputs ever join the transfer)."""
         if not self._temp.any():  # all-greedy pool: skip the top-p pipeline
-            return jax.device_get(sampling.greedy(logits))
-        keys = sampling.slot_step_keys(
-            self.base_key, jnp.asarray(self._rid), jnp.asarray(self._ngen),
-            jnp.asarray(self._stream),
-        )
-        return jax.device_get(
-            sampling.sample_slots(
+            toks = sampling.greedy(logits)
+        else:
+            keys = sampling.slot_step_keys(
+                self.base_key, jnp.asarray(self._rid), jnp.asarray(self._ngen),
+                jnp.asarray(self._stream),
+            )
+            toks = sampling.sample_slots(
                 logits, keys, jnp.asarray(self._temp), jnp.asarray(self._top_p)
             )
-        )
+        with self.spans("sched.sync"):
+            return jax.device_get(toks)
 
     def _record_step_metrics(self) -> None:
         self.n_decode_steps += 1
@@ -1106,8 +1130,10 @@ class Scheduler:
             # draft writes reach kv_len + w - 2, verify kv_len + w - 1:
             # grow every slot's blocks for its whole window up front (may
             # preempt — widths are rebuilt below for the survivors)
-            w = self._window_widths()
-            self._ensure_blocks(extra=np.maximum(w - 1, 0))
+            with self.spans("sched.blocks"):
+                w = self._window_widths()
+                self._ensure_blocks(extra=np.maximum(w - 1, 0))
+                self.pool.sync()
             if not self.active:
                 return None  # everything preempted back to the queue
         w = self._window_widths()
@@ -1123,15 +1149,16 @@ class Scheduler:
         base = np.zeros((self.slots,), np.int32)
         for slot, st in self.active.items():
             base[slot] = st.kv_len
-        self.pool.sync()
-        lengths = jnp.asarray(base)
-        window, cache = self._steps.draft_window(
-            self.model, e_step, k_step, self.params, self.pool.cache,
-            jnp.asarray(self._token), jnp.asarray(n_live), lengths,
-        )
-        logits, cache = self._steps.verify_step(
-            self.model, self.params, cache, window, jnp.asarray(w), lengths,
-        )
+        with self.spans("sched.launch"):
+            lengths = jnp.asarray(base)
+            window, cache = self._steps.draft_window(
+                self.model, e_step, k_step, self.params, self.pool.cache,
+                jnp.asarray(self._token), jnp.asarray(n_live), lengths,
+            )
+            logits, cache = self._steps.verify_step(
+                self.model, self.params, cache, window, jnp.asarray(w),
+                lengths,
+            )
         self.pool.cache = cache
         return ("spec", logits, window, w)
 
@@ -1143,18 +1170,21 @@ class Scheduler:
         and roll back every rejected suffix host-side (``kv_cache.rewind``
         + paged block-table truncation — no device gather or copy)."""
         samples, win = self._sample_window(logits, window)
-        self._record_step_metrics()
-        self.n_spec_steps += 1
-        now = self._now()
-        done = self._commit_window(samples, win, w, now)
-        # host-side rollback of every rejected suffix: ONE pool-wide
-        # lengths rewind (+ block-table truncation when paged), built
-        # after evictions so freed slots rewind to zero
-        new_len = np.zeros((self.slots,), np.int32)
-        for slot, st in self.active.items():
-            new_len[slot] = st.kv_len
-            self.pool.truncate(slot, st.kv_len)
-        self.pool.cache = kv_cache.rewind(self.pool.cache, jnp.asarray(new_len))
+        with self.spans("sched.commit"):
+            self._record_step_metrics()
+            self.n_spec_steps += 1
+            now = self._now()
+            done = self._commit_window(samples, win, w, now)
+            # host-side rollback of every rejected suffix: ONE pool-wide
+            # lengths rewind (+ block-table truncation when paged), built
+            # after evictions so freed slots rewind to zero
+            new_len = np.zeros((self.slots,), np.int32)
+            for slot, st in self.active.items():
+                new_len[slot] = st.kv_len
+                self.pool.truncate(slot, st.kv_len)
+            self.pool.cache = kv_cache.rewind(
+                self.pool.cache, jnp.asarray(new_len)
+            )
         return done
 
     @hot_path
@@ -1165,18 +1195,20 @@ class Scheduler:
         in for it — and ship (samples, window) to the host as the step's
         ONE device_get."""
         if not self._temp.any():  # all-greedy pool: skip the top-p pipeline
-            return jax.device_get((sampling.greedy(logits), window))
-        steps = jnp.asarray(self._ngen)[:, None] + jnp.arange(
-            logits.shape[1]
-        )[None]
-        keys = sampling.window_step_keys(
-            self.base_key, jnp.asarray(self._rid), steps,
-            jnp.asarray(self._stream),
-        )
-        samples = sampling.sample_window(
-            logits, keys, jnp.asarray(self._temp), jnp.asarray(self._top_p)
-        )
-        return jax.device_get((samples, window))
+            samples = sampling.greedy(logits)
+        else:
+            steps = jnp.asarray(self._ngen)[:, None] + jnp.arange(
+                logits.shape[1]
+            )[None]
+            keys = sampling.window_step_keys(
+                self.base_key, jnp.asarray(self._rid), steps,
+                jnp.asarray(self._stream),
+            )
+            samples = sampling.sample_window(
+                logits, keys, jnp.asarray(self._temp), jnp.asarray(self._top_p)
+            )
+        with self.spans("sched.sync"):
+            return jax.device_get((samples, window))
 
     def _commit_window(
         self, samples: np.ndarray, win: np.ndarray, w: np.ndarray, now: float
@@ -1243,7 +1275,8 @@ class Scheduler:
         router can dispatch EVERY replica's step before syncing any —
         JAX's async dispatch then overlaps replica compute on multi-device
         hosts. ``step()`` is the fused single-pool form."""
-        return self.step_finish(self.step_begin())
+        with self.spans.step("sched.step", self.n_decode_steps):
+            return self.step_finish(self.step_begin())
 
     @hot_path
     def step_begin(self):
@@ -1251,15 +1284,12 @@ class Scheduler:
         its device work asynchronously, and return an opaque pending
         handle for ``step_finish`` (None = the step did all its work on
         the host — e.g. everything was preempted back to the queue)."""
-        t = self.clock()
-        try:
+        with self.spans("sched.begin"):
             if self.chunked and len(self.chunk_mgr):
                 return self._begin_mixed()
             if self._spec_ready():
                 return self._begin_speculative()
             return self._begin_decode()
-        finally:
-            self.busy_s += self.clock() - t
 
     @hot_path
     def step_finish(self, pending) -> List[ServeRequest]:
@@ -1269,36 +1299,37 @@ class Scheduler:
         set the dispatch captured."""
         if pending is None:
             return []
-        t = self.clock()
-        try:
+        with self.spans("sched.finish"):
             kind = pending[0]
             if kind == "mixed":
                 return self._finish_mixed(*pending[1:])
             if kind == "spec":
                 return self._finish_speculative(*pending[1:])
             return self._finish_decode(*pending[1:])
-        finally:
-            self.busy_s += self.clock() - t
 
     @hot_path
     def _begin_decode(self):
-        if self.paged:
-            self._ensure_blocks()
-            if not self.active and not self.groups:
-                return None  # everything preempted back to the queue
-        self.pool.sync()
-        logits, cache = self._steps.decode_step(
-            self.model, self.params, self.pool.cache, jnp.asarray(self._token)
-        )
+        with self.spans("sched.blocks"):
+            if self.paged:
+                self._ensure_blocks()
+                if not self.active and not self.groups:
+                    return None  # everything preempted back to the queue
+            self.pool.sync()
+        with self.spans("sched.launch"):
+            logits, cache = self._steps.decode_step(
+                self.model, self.params, self.pool.cache,
+                jnp.asarray(self._token),
+            )
         self.pool.cache = cache
         return ("decode", logits)
 
     @hot_path
     def _finish_decode(self, logits) -> List[ServeRequest]:
         toks = self._sample(logits)
-        self._record_step_metrics()
-        now = self._now()
-        done = self._commit_decode(toks, now)
+        with self.spans("sched.commit"):
+            self._record_step_metrics()
+            now = self._now()
+            done = self._commit_decode(toks, now)
         done += self._commit_groups(logits, now)
         return done
 
@@ -1309,24 +1340,27 @@ class Scheduler:
         tokens (the plan from core/prefill.py), dispatched as ONE compiled
         executable — admission rides the pool-wide step instead of
         stalling it."""
-        self._ensure_blocks()  # decode growth first (victims incl. cursors)
-        # pack, then back every chunk's span with blocks; a starved cursor
-        # is excluded and the plan rebuilt so its budget share flows to
-        # cursors whose chunks ARE backed (no budget hoarding)
-        decode_slots = list(self.active) + [
-            s for g in self.groups.values() for s in g.slots
-        ]
-        starved: set = set()
-        while True:
-            plan = self.chunk_mgr.plan(self._token, decode_slots,
-                                       skip=starved)
-            kept = list(plan.chunks)
-            newly = [ch.slot for ch in plan.chunks
-                     if not self._ensure_or_reclaim(ch.slot,
-                                                    ch.start + ch.t - 1)]
-            if not newly:
-                break
-            starved.update(newly)
+        with self.spans("sched.blocks"):
+            # decode growth first (victims incl. cursors)
+            self._ensure_blocks()
+            # pack, then back every chunk's span with blocks; a starved
+            # cursor is excluded and the plan rebuilt so its budget share
+            # flows to cursors whose chunks ARE backed (no budget hoarding)
+            decode_slots = list(self.active) + [
+                s for g in self.groups.values() for s in g.slots
+            ]
+            starved: set = set()
+            while True:
+                plan = self.chunk_mgr.plan(self._token, decode_slots,
+                                           skip=starved)
+                kept = list(plan.chunks)
+                newly = [ch.slot for ch in plan.chunks
+                         if not self._ensure_or_reclaim(ch.slot,
+                                                        ch.start + ch.t - 1)]
+                if not newly:
+                    break
+                starved.update(newly)
+            self.pool.sync()
         if not kept:
             if self.active or self.groups:
                 # every pending chunk is block-starved: run the cheap
@@ -1354,30 +1388,32 @@ class Scheduler:
                 base[s] = g.kv_len
         for slot, cur in self.chunk_mgr.cursors.items():
             base[slot] = cur.pos
-        self.pool.sync()
-        logits, cache = self._steps.mixed_step(
-            self.model, self.params, self.pool.cache,
-            jnp.asarray(plan.tokens), jnp.asarray(plan.t_new),
-            jnp.asarray(base),
-        )
+        with self.spans("sched.launch"):
+            logits, cache = self._steps.mixed_step(
+                self.model, self.params, self.pool.cache,
+                jnp.asarray(plan.tokens), jnp.asarray(plan.t_new),
+                jnp.asarray(base),
+            )
         self.pool.cache = cache
         return ("mixed", logits, kept)
 
     @hot_path
     def _finish_mixed(self, logits, kept) -> List[ServeRequest]:
         toks = self._sample(logits)
-        self._record_step_metrics()
-        self.n_mixed_steps += 1
-        now = self._now()
-        done = self._commit_decode(toks, now)
+        with self.spans("sched.commit"):
+            self._record_step_metrics()
+            self.n_mixed_steps += 1
+            now = self._now()
+            done = self._commit_decode(toks, now)
         done += self._commit_groups(logits, now)
-        for ch in kept:
-            cur = self.chunk_mgr.advance(ch)
-            self.n_chunks += 1
-            self.n_chunk_tokens += ch.t
-            if cur.done:
-                self.chunk_mgr.remove(ch.slot)
-                self._finish_prefill(cur, toks[ch.slot], now)
+        with self.spans("sched.commit"):
+            for ch in kept:
+                cur = self.chunk_mgr.advance(ch)
+                self.n_chunks += 1
+                self.n_chunk_tokens += ch.t
+                if cur.done:
+                    self.chunk_mgr.remove(ch.slot)
+                    self._finish_prefill(cur, toks[ch.slot], now)
         return done
 
     def _finish_prefill(self, cur: ChunkCursor, first, now: float) -> None:
@@ -1414,16 +1450,18 @@ class Scheduler:
         token index) so preemption replay is key-identical regardless of
         slot placement or batch mates. Returns True when the group
         finished (its slots are already evicted)."""
-        key = jax.random.fold_in(
-            sampling.request_key(self.base_key, g.req.rid), g.n_generated
-        )
-        out = g.profile.step(g.pstate, logit_rows, key)
+        with self.spans("sched.group.profile"):
+            key = jax.random.fold_in(
+                sampling.request_key(self.base_key, g.req.rid), g.n_generated
+            )
+            out = g.profile.step(g.pstate, logit_rows, key)
         g.pstate = out.state
         # ONE host sync for everything this step needs on the host — the
         # feed tokens, the beam permutation, and the finish flags — instead
         # of a blocking np.asarray per field (device_get batches the pytree
         # into a single transfer; None leaves pass through untouched)
-        feed, perm, done = jax.device_get((out.feed, out.perm, out.done))
+        with self.spans("sched.group.sync"):
+            feed, perm, done = jax.device_get((out.feed, out.perm, out.done))
         if perm is not None:
             self._apply_group_perm(g, perm)
         g.n_generated += 1
@@ -1471,21 +1509,26 @@ class Scheduler:
         no per-step host list upload). Runs AFTER the device step wrote
         each stream's K/V at kv_len, hence the increment."""
         done: List[ServeRequest] = []
-        for g in list(self.groups.values()):
-            rows = logits[g.slot_rows]
-            g.kv_len += 1
-            if self._advance_group(g, rows, now):
-                done.append(g.req)
+        if not self.groups:
+            return done
+        with self.spans("sched.groups"):
+            for g in list(self.groups.values()):
+                with self.spans("sched.group", rid=g.req.rid):
+                    rows = logits[g.slot_rows]
+                    g.kv_len += 1
+                    if self._advance_group(g, rows, now):
+                        done.append(g.req)
         return done
 
     def _finish_group(self, g: GroupState, now: float) -> None:
         """Collapse the profile state into the request's output (beam:
         best hypothesis + normalized score) and free every stream slot."""
-        fin = g.profile.finalize(g.pstate)
-        toks = np.asarray(fin["tokens"])[0]
+        with self.spans("sched.group.sync"):
+            fin = jax.device_get(g.profile.finalize(g.pstate))
+        toks = fin["tokens"][0]
         g.req.tokens = [int(t) for t in toks[: g.n_generated]]
         if "scores" in fin:
-            g.req.score = float(np.asarray(fin["scores"])[0])
+            g.req.score = float(fin["scores"][0])
         g.req.t_done = now
         self.finished.append(g.req)
         self.groups.pop(g.slots[0], None)
