@@ -407,6 +407,11 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     correct, checks = judge(widest("served"), wrong_len, failed, cell.limits)
     log(f"compared {len(items)} requests, {sum(len(i.served) for i in items)}"
         f" served tokens, reference {time.perf_counter() - t_ref:.1f} s")
+    ref_peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                   for d in devs)
+    log(f"reference: {math.ceil(len(items) * streams / reference.CHUNK_ROWS)}"
+        f" chunks of {reference.CHUNK_ROWS} sequences a layer, device peak "
+        f"{ref_peak} B after it (window {mem_peak} B)")
 
     mlist = cell.per_layer if trace else cell.end_to_end
     metrics = {}

@@ -3,7 +3,9 @@
 ``repro`` and takes nothing the program made. It draws the weights again
 from the run's key, layer by layer, by the recipe the configuration
 states (see ``assumed.weights`` in each configuration file), so that a
-model larger than the chip in float32 still fits.
+model larger than the chip in float32 still fits; each layer runs over
+fixed chunks of ``CHUNK_ROWS`` sequences, so that its temporaries do not
+grow with the sample.
 
 Block (as the program runs it; each configuration file lists where that
 departs from the published model): pre-norm RMSNorm residual blocks,
@@ -29,6 +31,7 @@ import numpy as np
 HI = jax.lax.Precision.HIGHEST
 BQ = 128  # query rows per attention block; rows pad to a multiple of it
 HEAD_ROWS = 256  # logit rows per head block
+CHUNK_ROWS = 8  # sequences per layer call; the last chunk pads to it
 
 
 class Dims(NamedTuple):
@@ -224,22 +227,35 @@ def hidden_states(c: Dict[str, Any], key, seqs: List[np.ndarray],
                   quants: Sequence[bool]) -> Dict[bool, jnp.ndarray]:
     """Final-layer hidden states [N, T, d] of ``seqs`` (right-padded to a
     multiple of BQ) for each precision in ``quants``, one layer's weights
-    on the device at a time."""
+    on the device at a time, each layer run over chunks of CHUNK_ROWS
+    sequences (the last padded with rows of token 0, so that one
+    program serves every chunk)."""
     dm = dims(c)
+    n = len(seqs)
     t = -(-max(len(s) for s in seqs) // BQ) * BQ
-    toks = np.zeros((len(seqs), t), np.int32)
+    toks = np.zeros((-(-n // CHUNK_ROWS) * CHUNK_ROWS, t), np.int32)
     for i, s in enumerate(seqs):
         toks[i, : len(s)] = s
     ks = jax.random.split(key, dm.n_layers + 3)
     table = embedding(dm, ks[0])
-    x0 = jnp.take(table, jnp.asarray(toks), axis=0).astype(jnp.float32)
+    xs = {q: [jnp.take(table, jnp.asarray(toks[r : r + CHUNK_ROWS]), axis=0)
+              .astype(jnp.float32) for r in range(0, len(toks), CHUNK_ROWS)]
+          for q in quants}
     del table
-    xs = {q: x0 for q in quants}
     for i in range(dm.n_layers):
         w = layer_weights(dm, ks[2 + i])
-        xs = {q: layer_forward(dm, w, x, q) for q, x in xs.items()}
+        for q, chunks in xs.items():
+            for j, x in enumerate(chunks):
+                # one chunk in flight: dispatch would otherwise run ahead
+                # and hold every layer's weights and outputs at once
+                chunks[j] = layer_forward(dm, w, x, q).block_until_ready()
         del w
-    return xs
+    out = {}
+    for q in quants:  # one precision's chunks at a time beside its copy
+        chunks = xs.pop(q)
+        chunks[-1] = chunks[-1][: n - (len(chunks) - 1) * CHUNK_ROWS]
+        out[q] = jnp.concatenate(chunks)
+    return out
 
 
 def logit_gaps(c: Dict[str, Any], key, items: Sequence[Item],

@@ -33,33 +33,6 @@ def test_sound_run_is_correct(workload):
     assert out["attempted"] > 0 and out["failed"] == 0
 
 
-def _alter_tokens(sched):
-    """A token altered where it is produced: every 3rd step's sampled
-    tokens (greedy rows) and group picks (a boosted logit) are changed."""
-    n = [0]
-    sample, advance = sched._sample, sched._advance_group
-
-    def bad_sample(logits):
-        n[0] += 1
-        toks = np.array(sample(logits))
-        return (toks + 1) % logits.shape[-1] if n[0] % 3 == 0 else toks
-
-    def bad_advance(g, rows, now):
-        if g.n_generated % 3 == 2:
-            rows = rows.at[:, rows.shape[-1] - 1].add(1e4)
-        return advance(g, rows, now)
-
-    sched._sample, sched._advance_group = bad_sample, bad_advance
-
-
-@pytest.mark.parametrize("workload", CELLS)
-def test_altered_token_is_not_correct(workload):
-    out = _run(tiny.tiny_cell(workload), fault=_alter_tokens)
-    assert not out["correct"]
-    assert out["checks"]["max_logit_gap"]["value"] > \
-        out["checks"]["max_logit_gap"]["limit"]
-
-
 def _on_steps(fault):
     """Plant ``fault(step, *args)`` under the scheduler's decode and mixed
     steps."""
@@ -68,6 +41,104 @@ def _on_steps(fault):
             step = getattr(sched._steps, name)
             setattr(sched._steps, name, functools.partial(fault, step))
     return plant
+
+
+def _alter_tokens(sched):
+    """A token altered where it is produced: every 3rd step's sampled
+    tokens (greedy rows) are changed, and every 3rd step's logits leave
+    the step with the vocabulary's last logit boosted in every row, so a
+    group's pick follows it whatever code commits the group."""
+    n_sample, n_step = [0], [0]
+    sample = sched._sample
+
+    def bad_sample(logits):
+        n_sample[0] += 1
+        toks = np.array(sample(logits))
+        return (toks + 1) % logits.shape[-1] if n_sample[0] % 3 == 0 else toks
+
+    def boosted(step, *args):
+        logits, cache = step(*args)
+        n_step[0] += 1
+        if n_step[0] % 3 == 0:
+            logits = logits.at[:, -1].add(1e4)
+        return logits, cache
+
+    sched._sample = bad_sample
+    _on_steps(boosted)(sched)
+
+
+def _batched_group_commit(sched):
+    """Stand in a group commit that never calls ``_advance_group``, as one
+    batched group step over all resident groups would not: every group's
+    profile step is dispatched, then one sync serves them all. From then
+    on ``_advance_group`` raises where the step path reaches it (a group's
+    admission still takes its first token through it)."""
+    from repro.core import sampling
+
+    stepping = [False]
+    step, advance = sched.step, sched._advance_group
+
+    def guarded_step():
+        stepping[0] = True
+        try:
+            return step()
+        finally:
+            stepping[0] = False
+
+    def guarded_advance(g, rows, now):
+        if stepping[0]:
+            raise AssertionError("the step path called _advance_group")
+        return advance(g, rows, now)
+
+    def commit(logits, now):
+        groups = list(sched.groups.values())
+        outs = []
+        for g in groups:
+            key = jax.random.fold_in(
+                sampling.request_key(sched.base_key, g.req.rid), g.n_generated)
+            out = g.profile.step(g.pstate, logits[g.slot_rows], key)
+            g.pstate = out.state
+            outs.append((out.feed, out.done))
+        done = []
+        for g, (feed, fin) in zip(groups, jax.device_get(outs)):
+            g.kv_len += 1
+            g.n_generated += 1
+            g.req.t_tokens.append(now)
+            for i, s in enumerate(g.slots):
+                sched._token[s] = int(feed[i])
+                sched._ngen[s] = g.n_generated
+            if (fin is not None and bool(fin.all())
+                    or g.n_generated >= g.req.max_new):
+                sched._finish_group(g, now)
+                done.append(g.req)
+        return done
+
+    sched.step, sched._advance_group = guarded_step, guarded_advance
+    sched._commit_groups = commit
+
+
+def test_batched_group_commit_is_correct():
+    """The stand-in group commit serves what the program serves: a sound
+    run through it reads correct."""
+    out = _run(tiny.tiny_cell("chameleon-34b-l6.t2i"),
+               fault=_batched_group_commit)
+    assert out["correct"], out["checks"]
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_altered_token_is_not_correct(workload):
+    """The fault reads false through the program's own group commit and,
+    where the cell decodes in groups, through the stand-in that never
+    calls ``_advance_group``."""
+    plants = [_alter_tokens]
+    traffic = tiny.TRAFFIC[tiny.CELLS[workload][1]]
+    if traffic["decoding"]["profile"] == "contrastive":
+        plants.append(lambda s: (_batched_group_commit(s), _alter_tokens(s)))
+    for plant in plants:
+        out = _run(tiny.tiny_cell(workload), fault=plant)
+        assert not out["correct"]
+        assert out["checks"]["max_logit_gap"]["value"] > \
+            out["checks"]["max_logit_gap"]["limit"]
 
 
 def _state_unchanged(step, model, params, cache, *rest):

@@ -1,5 +1,7 @@
 """The plain reference against the program at a size a CPU test holds:
 the same weights drawn from the key, and logits that agree."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,6 +54,42 @@ def test_reference_logits_match_the_program(name):
                                  False).reshape(2, 40, -1)
     scale = float(jnp.abs(got).max())
     assert float(jnp.abs(got - want).max()) < 0.05 * scale
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["reference", "control"])
+def test_chunked_layers_match_one_call(monkeypatch, quant):
+    """Layers run over chunks of CHUNK_ROWS sequences, the last one padded,
+    give the hidden states of one call over every row."""
+    c = tiny.tiny_config(tiny.CHAMELEON)
+    key = jnp.asarray(harness.key_data(2**40 + 11))
+    rng = np.random.default_rng(5)
+    n = reference.CHUNK_ROWS + 3  # not a multiple of the chunk
+    seqs = [rng.integers(0, c["vocab_size"], int(k)).astype(np.int32)
+            for k in rng.integers(20, 150, n)]
+    got = reference.hidden_states(c, key, seqs, (quant,))[quant]
+    monkeypatch.setattr(reference, "CHUNK_ROWS", n)
+    want = reference.hidden_states(c, key, seqs, (quant,))[quant]
+    assert got.shape == want.shape == (n, 2 * reference.BQ, c["hidden_size"])
+    err = float(jnp.abs(got - want).max())
+    assert err <= 1e-5 * float(jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["reference", "control"])
+def test_chunk_program_fits_at_full_length(quant):
+    """The per-chunk layer program at the T-I cell's widths and its longest
+    sequence (22 prompt tokens + 1025 served, padded to 1152) holds its
+    arguments, temporaries and output in under 8 GB (CPU compile)."""
+    c = spec.load_json(spec.BENCH_DIR / "configs" / "chameleon-34b-l6.json")
+    dm = reference.dims(c)
+    w = jax.eval_shape(functools.partial(reference.layer_weights, dm),
+                       jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((reference.CHUNK_ROWS, 9 * reference.BQ, dm.d),
+                             jnp.float32)
+    ma = reference.layer_forward.lower(dm, w, x, quant).compile() \
+        .memory_analysis()
+    total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+             + ma.output_size_in_bytes)
+    assert total < 8e9, total
 
 
 def test_control_rounds_to_int8():
