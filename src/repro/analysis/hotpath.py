@@ -61,6 +61,9 @@ HOT_PATHS = frozenset({
     # pool geometry). Its per-round host code IS hot, and is decorated
     # directly: ReplicaRouter._round and the scheduler's two-phase
     # step_begin/step_finish split it drives.
+    # the lane-bank group step: one program a pool step advances every
+    # resident slot group of one decoding profile
+    "repro.core.profiles.lane_step",
 })
 
 

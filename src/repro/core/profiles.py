@@ -35,8 +35,16 @@ to five hooks the pool (core/scheduler.py) and the batch engines
 
 Hooks are vectorized over G independent groups laid out group-
 contiguously: row ``g * n_streams + s`` is group ``g``'s stream ``s``.
-The batch engines call them with G = batch; the scheduler with G = 1 per
-slot group, gathering each group's logits rows from the pool-wide step.
+The batch engines call them with G = batch. The scheduler keeps one
+*lane bank* per distinct profile value: the states of all its resident
+groups stacked on a leading lane axis, each lane a G = 1 state.
+``lane_step`` advances every lane in one jitted program (the unchanged
+``step`` under ``vmap``, each lane's logits rows gathered from the
+pool-wide step); ``put_lane`` writes a newly admitted group's state into
+its lane, and ``lane_finalize`` finalizes every lane at once. The
+programs take the profile as a static argument, so profiles are frozen
+dataclasses, hashed by value: equal specs from different requests (or
+schedulers) share one bank and one compiled program.
 
 The permutation returned by ``step`` is expressed in flat row indices
 (``perm[i]`` = the row whose cache stream ``i`` continues from). How it
@@ -48,6 +56,7 @@ shares common-prefix blocks copy-on-write — no device KV gather at all
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -219,7 +228,7 @@ class SpeculativeProfile(SamplingProfile):
 # beam search — the Seamless S-T/T-T strategy (paper Obs #4)
 # --------------------------------------------------------------------------
 
-@dataclass
+@dataclass(frozen=True)
 class BeamProfile(DecodingProfile):
     """Beam search over ``n_beams`` streams: every step rescores the
     ``n_beams * V`` candidates, keeps the top ``n_beams``, and re-binds
@@ -263,7 +272,7 @@ class BeamProfile(DecodingProfile):
 # contrastive / classifier-free guidance — the Chameleon T-I strategy
 # --------------------------------------------------------------------------
 
-@dataclass
+@dataclass(frozen=True)
 class ContrastiveProfile(DecodingProfile):
     """Two streams per request — conditional (sees the prompt) and
     unconditional (sees a null prompt) — each step runs BOTH (the paper's
@@ -308,7 +317,8 @@ class ContrastiveProfile(DecodingProfile):
         return toks, lens, extra
 
     def init(self, n_groups: int, max_new: int) -> Dict[str, Any]:
-        return {"tokens": jnp.zeros((n_groups, max_new), jnp.int32), "i": 0}
+        return {"tokens": jnp.zeros((n_groups, max_new), jnp.int32),
+                "i": jnp.zeros((), jnp.int32)}
 
     def step(self, state, logits, key) -> StepOut:
         from repro.models import vlm  # the paper's T-I math lives there
@@ -334,3 +344,47 @@ class ContrastiveProfile(DecodingProfile):
 def n_streams_of(profile: Optional[DecodingProfile]) -> int:
     """Streams a request occupies (1 when it has no profile spec)."""
     return 1 if profile is None else profile.n_streams
+
+
+# --------------------------------------------------------------------------
+# lane banks — every resident group of one profile in one program
+# --------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
+def lane_step(profile: DecodingProfile, state, logits: jnp.ndarray,
+              lanes: jnp.ndarray, base_key: jax.Array):
+    """One ``profile.step`` for every lane of a bank. ``lanes`` [L, S + 3]
+    int32 packs each lane's pool rows (its group's S slots), then its
+    rid, token index and live flag. Lane ``l`` steps on ``logits[rows[l]]``
+    under the key ``fold_in(request_key(base_key, rid), n_generated)`` —
+    the key a group's own step would use — and a dead lane keeps its
+    state. Returns (state, feed [L, S], perm [L, S] or None, done [L] or
+    None)."""
+    rows, rid, n_gen = lanes[:, :-3], lanes[:, -3], lanes[:, -2]
+    live = lanes[:, -1] > 0
+
+    def one(st, lane_rows, r, n):
+        key = jax.random.fold_in(sampling.request_key(base_key, r), n)
+        out = profile.step(st, logits[lane_rows], key)
+        return out.state, out.feed, out.perm, out.done
+
+    new, feed, perm, done = jax.vmap(one)(state, rows, rid, n_gen)
+    state = jax.tree.map(
+        lambda a, b: jnp.where(live.reshape((-1,) + (1,) * (a.ndim - 1)),
+                               a, b),
+        new, state)
+    return state, feed, perm, None if done is None else done[:, 0]
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def put_lane(state, lane, lane_state):
+    """Write one lane's G = 1 state into a bank (``lane`` is traced: one
+    program for every lane)."""
+    return jax.tree.map(lambda b, x: b.at[lane].set(x), state, lane_state)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def lane_finalize(profile: DecodingProfile, state) -> Dict[str, jnp.ndarray]:
+    """``profile.finalize`` of every lane: each output gains a leading
+    lane axis over its G = 1 shape."""
+    return jax.vmap(profile.finalize)(state)

@@ -158,12 +158,13 @@ def sample_window(
 # Beam search (Seamless profile, Obs #4)
 # --------------------------------------------------------------------------
 
+@jax.tree_util.register_dataclass
 @dataclass
 class BeamState:
     tokens: jnp.ndarray  # [B*K, L] generated so far (right-padded)
     scores: jnp.ndarray  # [B*K] cumulative log-prob
     finished: jnp.ndarray  # [B*K] bool
-    step: int
+    step: jnp.ndarray  # int32 scalar: tokens generated so far
 
 
 def beam_init(batch: int, n_beams: int, max_len: int) -> BeamState:
@@ -174,7 +175,7 @@ def beam_init(batch: int, n_beams: int, max_len: int) -> BeamState:
         tokens=jnp.zeros((batch * n_beams, max_len), jnp.int32),
         scores=scores,
         finished=jnp.zeros((batch * n_beams,), bool),
-        step=0,
+        step=jnp.zeros((), jnp.int32),
     )
 
 

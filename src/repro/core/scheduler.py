@@ -77,10 +77,16 @@ decodes: a multi-stream ``DecodingProfile`` (beam, contrastive) is
 admitted as a *slot group* of ``profile.n_streams`` slots — acquired,
 evicted, and preempted all-or-nothing, with the group treated as ONE
 unit by priority ordering and victim selection. Group streams ride the
-same pool-wide decode executable as everyone else; between the decode
-step and the commit, the scheduler gathers each group's logits rows and
-lets its profile pick the streams' next tokens plus an optional
-intra-group cache permutation (beam's Obs #4 KV reorder). Under
+same pool-wide decode executable as everyone else. A group's decoding
+state lives in a lane of its profile's *lane bank* (``LaneBank``; one per
+distinct profile value, ``slots // n_streams`` lanes): after the decode
+step ONE jitted program per bank (``profiles.lane_step``) gathers every
+lane's logits rows and runs the profile's step on all of them, picking
+each stream's next token plus an optional intra-group cache permutation
+(beam's Obs #4 KV reorder); its outputs join the step's sampled tokens
+in the step's one ``device_get``. A group's first step, on its prefill
+logits, runs at admission, and its state then moves into a free lane.
+Under
 ``paged=True`` that permutation is a pure host-side block-table rewrite
 with copy-on-write sharing of common-prefix blocks (``BlockPool.share``
 / ``permute_group`` / ``ensure_writable``) — no device KV gather ever
@@ -132,11 +138,13 @@ inside a named span (``self.spans``) that lands in a profiler trace as a
 ``sched.step`` (an XProf step) holds ``sched.begin`` (children
 ``sched.blocks``: block growth and the block-table upload;
 ``sched.launch``: the jitted step call) and ``sched.finish`` (children
-``sched.sync``: the step's device_get; ``sched.commit``; ``sched.groups``,
-one ``sched.group`` per resident group with ``sched.group.profile`` and
-``sched.group.sync``). ``sched.admit`` wraps an admission, with
-``sched.prefill`` around its prefill dispatch. Every host<->device round
-trip is a span named ``*.sync``.
+``sched.sync``: the step's one device_get; ``sched.commit``; and, with
+resident groups, ``sched.groups`` twice: the lane banks' dispatch, meta
+``lanes``, before the sync, and the groups' commit after it, holding
+``sched.groups.sync`` when a group finishes). ``sched.admit`` wraps an
+admission, with ``sched.prefill`` around its prefill dispatch and
+``sched.admit.sync`` around its first token's transfer. Every
+host<->device round trip is a span named ``*.sync``.
 """
 from __future__ import annotations
 
@@ -266,22 +274,72 @@ class SlotState:
 class GroupState:
     """Host-side view of one occupied slot GROUP (a multi-stream decoding
     profile): stream ``i`` lives in pool slot ``slots[i]``. Streams advance
-    in lockstep, so one ``kv_len`` covers the whole group; all mutable
-    decoding state is ``pstate`` (the profile's, re-initialized on
-    preemption replay). ``admit_seq`` orders the group as ONE unit against
-    other residents for block growth and victim selection."""
+    in lockstep, so one ``kv_len`` covers the whole group. Its decoding
+    state lives in lane ``lane`` of ``bank`` (the profile's lane bank;
+    ``pstate`` reads it, G = 1) and is re-initialized on preemption
+    replay. ``admit_seq`` orders the group as ONE unit against other
+    residents for block growth and victim selection."""
 
     req: ServeRequest
     slots: List[int]
     profile: profiles.DecodingProfile
-    pstate: Any
+    bank: "LaneBank"
+    lane: int = -1  # -1 until the first step's state is in the bank
     n_generated: int = 0
     kv_len: int = 0
     admit_seq: int = 0
-    # device-resident copy of ``slots`` (int32), built once at admission:
-    # the per-step logits row gather indexes with it directly instead of
-    # re-uploading the host list every step
-    slot_rows: Any = None
+
+    @property
+    def pstate(self) -> Any:
+        """The group's profile state (G = 1): its lane of the bank."""
+        return jax.tree.map(lambda x: x[self.lane], self.bank.state)
+
+
+class LaneBank:
+    """The stacked decoding state of every resident group of one profile
+    value, advanced by ONE jitted program a step (``profiles.lane_step``).
+    ``n_lanes = slots // n_streams`` covers every group the pool can hold;
+    a group takes a free lane at admission and returns it at finish or
+    preemption. ``lanes`` is the host mirror the program reads, one
+    int32 row per lane: the group's slots, its rid, its token index and
+    a live flag (dead lanes are stepped but keep their state)."""
+
+    def __init__(self, profile: profiles.DecodingProfile, n_lanes: int,
+                 max_new: int, device=None):
+        self.profile = profile
+        # n_lanes copies of a fresh G = 1 state on a leading lane axis
+        self.state = jax.tree.map(
+            lambda x: jnp.repeat(x[None], n_lanes, axis=0),
+            profile.init(1, max_new),
+        )
+        if device is not None:
+            self.state = jax.device_put(self.state, device)
+        self.lanes = np.zeros((n_lanes, profile.n_streams + 3), np.int32)
+        self._free = list(range(n_lanes - 1, -1, -1))  # lowest lane first
+        self.n_live = 0
+
+    def admit(self, g: GroupState, lane_state) -> None:
+        """Give ``g`` a free lane holding ``lane_state`` (its state after
+        the first step)."""
+        g.lane = self._free.pop()
+        self.state = profiles.put_lane(self.state, g.lane, lane_state)
+        self.lanes[g.lane] = [*g.slots, g.req.rid, g.n_generated, 1]
+        self.n_live += 1
+
+    def release(self, g: GroupState) -> None:
+        self.lanes[g.lane] = 0
+        self._free.append(g.lane)
+        g.lane = -1
+        self.n_live -= 1
+
+    def launch(self, logits, base_key):
+        """Dispatch this step's program over every lane: (feed, perm,
+        done) device arrays, one row per lane."""
+        self.state, *out = profiles.lane_step(
+            self.profile, self.state, logits, jnp.asarray(self.lanes),
+            base_key,
+        )
+        return out
 
 
 class Scheduler:
@@ -401,8 +459,10 @@ class Scheduler:
         )
         self.priority_boost_after = priority_boost_after
         self.active: Dict[int, SlotState] = {}
-        # slot groups (multi-stream profiles), keyed by their first slot
+        # slot groups (multi-stream profiles), keyed by their first slot,
+        # and their states: one lane bank per distinct profile value
         self.groups: Dict[int, GroupState] = {}
+        self._banks: Dict[profiles.DecodingProfile, LaneBank] = {}
         self.waiting: Deque[ServeRequest] = deque()
         self.finished: List[ServeRequest] = []
         # host mirrors of per-slot decode state (free slots: greedy + rid 0;
@@ -422,6 +482,8 @@ class Scheduler:
         self.n_chunks = 0
         self.n_chunk_tokens = 0
         self.n_group_admissions = 0
+        self.n_group_batches = 0  # lane-bank programs dispatched
+        self.group_lane_trace: List[int] = []  # live lanes, per program
         # speculative decoding accounting: a "spec slot-step" is one slot
         # carrying a >= 2-wide draft/verify window through one pool step;
         # the histogram keys on tokens committed by that slot that step
@@ -711,11 +773,14 @@ class Scheduler:
                 rows.append(logits)
             logit_rows = jnp.concatenate(rows, axis=0)
         req.t_admit = now
+        bank = self._banks.get(prof)
+        if bank is None:
+            bank = self._banks[prof] = LaneBank(
+                prof, self.slots // s_n, self.max_new_cap, self.device
+            )
         g = GroupState(
-            req=req, slots=slots, profile=prof,
-            pstate=prof.init(1, req.max_new), kv_len=n_prompt,
+            req=req, slots=slots, profile=prof, bank=bank, kv_len=n_prompt,
             admit_seq=self._seq,
-            slot_rows=jnp.asarray(slots, jnp.int32),
         )
         self._seq += 1
         self.n_group_admissions += 1
@@ -958,6 +1023,7 @@ class Scheduler:
         stream's slot is evicted and the profile state discarded)."""
         if isinstance(st, GroupState):
             del self.groups[st.slots[0]]
+            st.bank.release(st)
             for s in st.slots:
                 self.pool.evict(s)
                 self._temp[s] = 0.0
@@ -1026,22 +1092,28 @@ class Scheduler:
 
     # ---- decode ----------------------------------------------------------
     @hot_path
-    def _sample(self, logits) -> np.ndarray:
-        """Per-slot sampling; the ONE host sync of a plain decode step
-        (``device_get``, not ``np.asarray`` — explicit, and batching-
-        friendly if more per-step outputs ever join the transfer)."""
+    def _sample(self, logits):
+        """Per-slot sampling, dispatched: the [slots] tokens stay on the
+        device until the step's one round trip (``_sync_step``)."""
         if not self._temp.any():  # all-greedy pool: skip the top-p pipeline
-            toks = sampling.greedy(logits)
-        else:
-            keys = sampling.slot_step_keys(
-                self.base_key, jnp.asarray(self._rid), jnp.asarray(self._ngen),
-                jnp.asarray(self._stream),
-            )
-            toks = sampling.sample_slots(
-                logits, keys, jnp.asarray(self._temp), jnp.asarray(self._top_p)
-            )
+            return sampling.greedy(logits)
+        keys = sampling.slot_step_keys(
+            self.base_key, jnp.asarray(self._rid), jnp.asarray(self._ngen),
+            jnp.asarray(self._stream),
+        )
+        return sampling.sample_slots(
+            logits, keys, jnp.asarray(self._temp), jnp.asarray(self._top_p)
+        )
+
+    @hot_path
+    def _sync_step(self, logits):
+        """The step's ONE host<->device round trip: the per-slot samples
+        and, with resident groups, every lane bank's (feed, perm, done),
+        in one ``device_get``. Returns (host tokens, host bank outputs)."""
+        toks = self._sample(logits)
+        outs = self._launch_groups(logits)
         with self.spans("sched.sync"):
-            return jax.device_get(toks)
+            return jax.device_get((toks, outs))
 
     def _record_step_metrics(self) -> None:
         self.n_decode_steps += 1
@@ -1325,12 +1397,12 @@ class Scheduler:
 
     @hot_path
     def _finish_decode(self, logits) -> List[ServeRequest]:
-        toks = self._sample(logits)
+        toks, outs = self._sync_step(logits)
         with self.spans("sched.commit"):
             self._record_step_metrics()
             now = self._now()
             done = self._commit_decode(toks, now)
-        done += self._commit_groups(logits, now)
+        done += self._commit_lanes(outs, now)
         return done
 
     @hot_path
@@ -1399,13 +1471,13 @@ class Scheduler:
 
     @hot_path
     def _finish_mixed(self, logits, kept) -> List[ServeRequest]:
-        toks = self._sample(logits)
+        toks, outs = self._sync_step(logits)
         with self.spans("sched.commit"):
             self._record_step_metrics()
             self.n_mixed_steps += 1
             now = self._now()
             done = self._commit_decode(toks, now)
-        done += self._commit_groups(logits, now)
+        done += self._commit_lanes(outs, now)
         with self.spans("sched.commit"):
             for ch in kept:
                 cur = self.chunk_mgr.advance(ch)
@@ -1420,7 +1492,7 @@ class Scheduler:
         """The final chunk's last-lane logits ARE the first-token logits:
         commit the request's first token and flip the slot from prefill to
         decode (its device length already equals the prompt length)."""
-        first = int(first)  # host value from _sample's device_get
+        first = int(first)  # host value from _sync_step's device_get
         req = cur.req
         req.t_first = now
         req.tokens.append(first)
@@ -1441,41 +1513,47 @@ class Scheduler:
         self._ngen[cur.slot] = 1
 
     # ---- slot groups (multi-stream decoding profiles) ---------------------
-    @hot_path
     def _advance_group(self, g: GroupState, logit_rows, now: float) -> bool:
-        """One profile step for one slot group: the profile consumes the
-        group's [n_streams, V] logits rows, picks every stream's next feed
-        token and the optional intra-group cache permutation, and reports
-        the finish condition. The step key derives from (rid, stream 0,
-        token index) so preemption replay is key-identical regardless of
-        slot placement or batch mates. Returns True when the group
-        finished (its slots are already evicted)."""
-        with self.spans("sched.group.profile"):
-            key = jax.random.fold_in(
-                sampling.request_key(self.base_key, g.req.rid), g.n_generated
-            )
-            out = g.profile.step(g.pstate, logit_rows, key)
-        g.pstate = out.state
-        # ONE host sync for everything this step needs on the host — the
-        # feed tokens, the beam permutation, and the finish flags — instead
-        # of a blocking np.asarray per field (device_get batches the pytree
-        # into a single transfer; None leaves pass through untouched)
-        with self.spans("sched.group.sync"):
+        """The group's FIRST profile step, at admission: a fresh G = 1
+        state steps on the prefill's [n_streams, V] logits rows (eagerly,
+        once a request), its feed tokens are committed, and the state
+        moves into a lane of the group's bank, where ``_launch_groups``
+        advances it from then on. The key is the one the bank uses,
+        (rid, token index 0), so preemption replay is key-identical.
+        Returns True when the group finished (its slots already
+        evicted)."""
+        state = g.profile.init(1, self.max_new_cap)
+        key = jax.random.fold_in(
+            sampling.request_key(self.base_key, g.req.rid), g.n_generated
+        )
+        out = g.profile.step(state, logit_rows, key)
+        with self.spans("sched.admit.sync"):
             feed, perm, done = jax.device_get((out.feed, out.perm, out.done))
+        if self._commit_group(g, feed, perm, done, now):
+            with self.spans("sched.admit.sync"):
+                fin = jax.device_get(g.profile.finalize(out.state))
+            self._finish_group(g, fin, now)
+            return True
+        g.bank.admit(g, out.state)
+        return False
+
+    @hot_path
+    def _commit_group(self, g: GroupState, feed: np.ndarray, perm, done,
+                      now: float) -> bool:
+        """Commit one group's step from host values: the streams' feed
+        tokens [S], the optional intra-group cache permutation [S] and
+        done flag. Returns True when the group is finished."""
         if perm is not None:
             self._apply_group_perm(g, perm)
         g.n_generated += 1
         if g.n_generated == 1:
             g.req.t_first = now
         g.req.t_tokens.append(now)
-        for i, s in enumerate(g.slots):
-            self._token[s] = int(feed[i])
-            self._ngen[s] = g.n_generated
-        finished = done is not None and bool(done.all())
-        if finished or g.n_generated >= g.req.max_new:
-            self._finish_group(g, now)
-            return True
-        return False
+        self._token[g.slots] = feed
+        self._ngen[g.slots] = g.n_generated
+        return (done is not None and done.all()) or (
+            g.n_generated >= g.req.max_new
+        )
 
     @hot_path
     def _apply_group_perm(self, g: GroupState, perm: np.ndarray) -> None:
@@ -1503,28 +1581,67 @@ class Scheduler:
             self.n_cache_reorders += 1
 
     @hot_path
-    def _commit_groups(self, logits, now: float) -> List[ServeRequest]:
-        """Advance every resident group on the pool-wide step's logits
-        (each group's rows gathered by its admission-time device index —
-        no per-step host list upload). Runs AFTER the device step wrote
-        each stream's K/V at kv_len, hence the increment."""
-        done: List[ServeRequest] = []
+    def _launch_groups(self, logits) -> list:
+        """Dispatch one ``profiles.lane_step`` per lane bank with live
+        lanes, on the pool-wide step's logits: every resident group
+        advances in its bank's one program. Returns the banks' device
+        outputs (None for a bank with no resident group); nothing is
+        dispatched without resident groups."""
         if not self.groups:
-            return done
-        with self.spans("sched.groups"):
-            for g in list(self.groups.values()):
-                with self.spans("sched.group", rid=g.req.rid):
-                    rows = logits[g.slot_rows]
-                    g.kv_len += 1
-                    if self._advance_group(g, rows, now):
-                        done.append(g.req)
-        return done
+            return []
+        with self.spans("sched.groups", lanes=len(self.groups)):
+            outs = []
+            for bank in self._banks.values():
+                if not bank.n_live:
+                    outs.append(None)
+                    continue
+                outs.append(bank.launch(logits, self.base_key))
+                self.n_group_batches += 1
+                self.group_lane_trace.append(bank.n_live)
+            return outs
 
-    def _finish_group(self, g: GroupState, now: float) -> None:
-        """Collapse the profile state into the request's output (beam:
-        best hypothesis + normalized score) and free every stream slot."""
-        with self.spans("sched.group.sync"):
-            fin = jax.device_get(g.profile.finalize(g.pstate))
+    @hot_path
+    def _commit_lanes(self, outs: list, now: float) -> List[ServeRequest]:
+        """Commit every resident group from its bank's host outputs (the
+        step wrote each stream's K/V at kv_len, hence the increment);
+        finishing groups read their outputs in one more round trip."""
+        if not outs:
+            return []
+        with self.spans("sched.groups"):
+            host = dict(zip(self._banks.values(), outs))
+            finishing: List[GroupState] = []
+            for g in list(self.groups.values()):
+                feed, perm, done = host[g.bank]
+                g.kv_len += 1
+                lane = g.lane
+                if self._commit_group(
+                    g, feed[lane], None if perm is None else perm[lane],
+                    None if done is None else done[lane], now,
+                ):
+                    finishing.append(g)
+                else:
+                    g.bank.lanes[lane, -2] = g.n_generated  # token index
+            if finishing:
+                banks = list(dict.fromkeys(g.bank for g in finishing))
+                with self.spans("sched.groups.sync"):
+                    fins = jax.device_get([
+                        profiles.lane_finalize(b.profile, b.state)
+                        for b in banks
+                    ])
+                fin_of = dict(zip(banks, fins))
+                for g in finishing:
+                    lane = g.lane
+                    self._finish_group(
+                        g, {k: v[lane] for k, v in fin_of[g.bank].items()},
+                        now,
+                    )
+        return [g.req for g in finishing]
+
+    def _finish_group(self, g: GroupState, fin: Dict[str, np.ndarray],
+                      now: float) -> None:
+        """Write the group's finalized host output (G = 1; beam: best
+        hypothesis + normalized score) into its request and free every
+        stream slot and its lane."""
         toks = fin["tokens"][0]
         g.req.tokens = [int(t) for t in toks[: g.n_generated]]
         if "scores" in fin:
@@ -1532,6 +1649,8 @@ class Scheduler:
         g.req.t_done = now
         self.finished.append(g.req)
         self.groups.pop(g.slots[0], None)
+        if g.lane >= 0:
+            g.bank.release(g)
         for s in g.slots:
             self.pool.evict(s)
             self._temp[s] = 0.0

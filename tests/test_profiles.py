@@ -520,3 +520,195 @@ def test_block_share_permute_cow_against_dense_mirror():
         pool.evict(s)
     assert sorted(pool._free_blocks) == list(range(1, nb))
     assert (np.asarray(pool._ref)[1:] == 0).all()
+
+
+# ------------------------------------ lane banks == the per-group path
+def _eager_group_path(sched):
+    """The per-group path the lane banks replaced, as the reference: each
+    group keeps its own G = 1 state (sized by its request's ``max_new``),
+    and every step gathers its logits rows, runs ``profile.step`` eagerly
+    under ``fold_in(request_key(base, rid), n_generated)``, and reads the
+    result back in a get of its own. Lanes are still taken and returned
+    (preemption releases them) but never read."""
+    states = {}  # id(group) -> (group, its G = 1 state)
+    seen = {}
+
+    def commit_one(g, out, now):
+        feed, perm, fin = jax.device_get((out.feed, out.perm, out.done))
+        if perm is not None:
+            sched._apply_group_perm(g, perm)
+        g.n_generated += 1
+        g.req.t_tokens.append(now)
+        for i, s in enumerate(g.slots):
+            sched._token[s] = int(feed[i])
+            sched._ngen[s] = g.n_generated
+        if not ((fin is not None and bool(fin.all()))
+                or g.n_generated >= g.req.max_new):
+            states[id(g)] = (g, out.state)
+            return False
+        res = jax.device_get(g.profile.finalize(out.state))
+        g.req.tokens = [int(t) for t in res["tokens"][0][: g.n_generated]]
+        if "scores" in res:
+            g.req.score = float(res["scores"][0])
+        g.req.t_done = now
+        sched.finished.append(g.req)
+        sched.groups.pop(g.slots[0])
+        if g.lane >= 0:
+            g.bank.release(g)
+        for s in g.slots:
+            sched.pool.evict(s)
+        return True
+
+    def step_of(g, st, rows, now):
+        key = jax.random.fold_in(
+            sampling.request_key(sched.base_key, g.req.rid), g.n_generated)
+        return commit_one(g, g.profile.step(st, rows, key), now)
+
+    def advance(g, rows, now):
+        if step_of(g, g.profile.init(1, g.req.max_new), rows, now):
+            return True
+        g.bank.admit(g, g.profile.init(1, sched.max_new_cap))  # lane only
+        return False
+
+    def launch(logits):
+        seen["logits"] = logits
+        return []
+
+    def commit(outs, now):
+        done = []
+        for g in list(sched.groups.values()):
+            _, st = states.pop(id(g))
+            g.kv_len += 1
+            rows = seen["logits"][jnp.asarray(g.slots)]
+            if step_of(g, st, rows, now):
+                done.append(g.req)
+        return done
+
+    sched._advance_group = advance
+    sched._launch_groups, sched._commit_lanes = launch, commit
+
+
+def _drive(sched, reqs, admit_at):
+    """Hand request ``i`` over at step ``admit_at[i]``; serve to the end.
+    Returns {rid: (tokens, score)} and the live lanes of every step."""
+    todo = sorted(zip(admit_at, range(len(reqs))))
+    n, lanes = 0, []
+    while todo or sched.waiting or sched.has_work:
+        while todo and todo[0][0] <= n:
+            sched.submit([reqs[todo.pop(0)[1]]])
+        sched._admit(sched._now())
+        if sched.has_work:
+            lanes.append(sorted((g.lane, g.n_generated, g.req.rid)
+                                for g in sched.groups.values()))
+            sched.step()
+        n += 1
+    return {r.rid: (list(r.tokens), r.score) for r in sched.finished}, lanes
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+@pytest.mark.parametrize("kind", ["cfg_greedy", "cfg_top_p", "beam"])
+def test_lane_bank_serves_what_the_per_group_step_serves(llama, kind, paged):
+    """Groups admitted at different steps (so lanes carry different token
+    indices), a dead lane, a lane reused after a finish, a plain request
+    beside them and, on the paged pool, a preemption replay: the lane
+    banks serve token for token (and beam score for score) what the
+    per-group eager step serves on the same logits."""
+    model, params = llama
+    v = model.config.vocab_size
+    rng = np.random.default_rng(21)
+    prof = {
+        "cfg_greedy": profiles.ContrastiveProfile(uncond_token=0,
+                                                  guidance=2.5),
+        # the smoke model's logit gaps are ~100 nats: only a flat
+        # distribution makes the per-lane keys decide the tokens
+        "cfg_top_p": profiles.ContrastiveProfile(
+            uncond_token=0, guidance=1.5, temperature=50.0, top_p=0.9),
+        "beam": profiles.BeamProfile(n_beams=2, eos_id=2),
+    }[kind]
+    lens, max_new = [6, 5, 7, 8, 5, 4], [10, 3, 8, 6, 9, 5]
+    prompts = [rng.integers(1, v, size=n) for n in lens]
+    admit_at = [0, 1, 2, 4, 5, 3]
+
+    def reqs():
+        return [
+            ServeRequest(rid=i, prompt=prompts[i], max_new=max_new[i],
+                         # each request its own (equal) profile object
+                         profile=None if i == 5 else dataclasses.replace(prof))
+            for i in range(6)
+        ]
+
+    def serve(reference):
+        # max_len 19, block 4: max_blocks 5, a group needs <= 10 of the
+        # 12 usable blocks, so concurrent groups preempt each other
+        sched = Scheduler(model, params, slots=6, pad_to=PAD_TO,
+                          max_new_cap=10, paged=paged, block_size=4,
+                          num_blocks=13 if paged else None,
+                          base_key=jax.random.PRNGKey(7))
+        if reference:
+            _eager_group_path(sched)
+        return sched, *_drive(sched, reqs(), admit_at)
+
+    ref_sched, want, _ = serve(reference=True)
+    sched, got, lanes = serve(reference=False)
+    assert len(got) == 6 and got == want
+    assert ref_sched.n_group_batches == 0
+    assert sched.n_group_batches == sum(1 for ls in lanes if ls) > 0
+    assert len(sched._banks) == 1  # equal profiles share one bank
+    assert sched.n_preemptions == ref_sched.n_preemptions
+    if paged:
+        assert sched.n_preemptions >= 1
+    # lanes at different token indices in one program, a dead lane, and a
+    # lane that served two requests
+    assert any(len({n for _, n, _ in ls}) > 1 for ls in lanes)
+    assert min(sched.group_lane_trace) < sched.slots // 2
+    rids = {}
+    for ls in lanes:
+        for lane, _, rid in ls:
+            rids.setdefault(lane, set()).add(rid)
+    assert max(len(r) for r in rids.values()) > 1, "no lane was reused"
+
+
+def test_equal_profiles_compile_one_bank_program(llama):
+    """A second scheduler of the same geometry, serving requests with
+    equal but distinct profile objects (the benchmark's warm-up scheduler
+    and its window's), adds no entry to the bank programs' jit caches."""
+    model, params = llama
+    rng = np.random.default_rng(5)
+    v = model.config.vocab_size
+
+    def serve():
+        sched = Scheduler(model, params, slots=4, pad_to=PAD_TO,
+                          max_new_cap=6, paged=True, block_size=4)
+        sched.run([
+            ServeRequest(rid=i, prompt=rng.integers(1, v, size=5),
+                         max_new=3 + i,
+                         profile=profiles.ContrastiveProfile(
+                             uncond_token=0, guidance=2.0))
+            for i in range(3)
+        ])
+        assert len(sched._banks) == 1 and sched.n_group_batches > 0
+
+    serve()
+    progs = (profiles.lane_step, profiles.put_lane, profiles.lane_finalize)
+    sizes = [p._cache_size() for p in progs]
+    serve()
+    assert [p._cache_size() for p in progs] == sizes
+
+
+def test_plain_sampling_dispatches_no_bank_program(llama):
+    """Traffic without groups never reaches the lane banks: no program,
+    no ``sched.groups`` span, one ``*.sync`` a step."""
+    model, params = llama
+    rng = np.random.default_rng(6)
+    v = model.config.vocab_size
+    sched = Scheduler(model, params, slots=3, pad_to=PAD_TO, max_new_cap=6,
+                      paged=True, chunked=True, block_size=4,
+                      prefill_budget=8)
+    sched.run([ServeRequest(rid=i, prompt=rng.integers(1, v, size=5),
+                            max_new=6, temperature=0.7 * i)
+               for i in range(4)])
+    t = sched.spans.totals()
+    assert sched.n_group_batches == 0 and not sched.group_lane_trace
+    assert "sched.groups" not in t and not sched._banks
+    syncs = sum(c["count"] for n, c in t.items() if n.endswith(".sync"))
+    assert syncs == t["sched.finish"]["count"] == sched.n_decode_steps

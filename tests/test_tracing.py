@@ -1,6 +1,7 @@
 """Host spans (core/tracing.py) and the scheduler's use of them: totals
-under a fake clock, one ``sched.finish`` and one ``*.sync`` per round trip
-in every step of a CFG group mix, ``busy_s`` read from the spans, and the
+under a fake clock, one ``sched.finish`` and one ``sched.sync`` in every
+step of a CFG group mix (plus one ``*.sync`` in a step where a group
+finishes), ``busy_s`` read from the spans, and the
 spans' ``TraceAnnotation`` events in a profiler trace."""
 import glob
 import os
@@ -97,9 +98,11 @@ def _count(totals, name):
 
 
 def test_every_step_one_finish_and_one_sync_per_round_trip(chameleon):
-    """Per step: one ``sched.finish``, one ``sched.sync`` (the step's
-    device_get), one ``sched.group.sync`` per resident group and one more
-    for each group that finishes (its output's transfer)."""
+    """Per step: one ``sched.finish`` and one ``sched.sync`` (the step's
+    device_get: sampled tokens and every lane bank's outputs), plus one
+    ``*.sync`` when any group finishes (the finished groups' outputs);
+    two ``sched.groups`` spans (bank dispatch, group commit) while groups
+    are resident, and one bank program."""
     model, params, offset = chameleon
     sched = _scheduler(model, params)
     waiting = _cfg_mix(offset)
@@ -111,6 +114,7 @@ def test_every_step_one_finish_and_one_sync_per_round_trip(chameleon):
             waiting.pop(0)
         before = sched.spans.totals()
         n_groups, n_done = len(sched.groups), len(sched.finished)
+        n_batches = sched.n_group_batches
         sched.step()
         after = sched.spans.totals()
         n_fin = len(sched.finished) - n_done
@@ -119,21 +123,25 @@ def test_every_step_one_finish_and_one_sync_per_round_trip(chameleon):
             return _count(after, name) - _count(before, name)
 
         assert added("sched.finish") == added("sched.sync") == 1
-        assert _syncs(after) - _syncs(before) == 1 + n_groups + n_fin
-        assert added("sched.group") == n_groups
+        assert _syncs(after) - _syncs(before) == 1 + (n_fin > 0)
+        assert added("sched.groups") == (2 if n_groups else 0)
+        assert sched.n_group_batches - n_batches == (1 if n_groups else 0)
+        if n_groups:
+            assert sched.group_lane_trace[-1] == n_groups
         n_steps += 1
     t = sched.spans.totals()
     assert n_steps == sched.n_decode_steps == _count(t, "sched.step") > 0
     assert _count(t, "sched.admit") == 3
     assert _count(t, "sched.prefill") == 6  # cond and uncond prompt each
     assert len(sched.finished) == 3
-    # every child lies inside its parent's time (a group's profile step
-    # also runs at admission, outside any ``sched.group``)
+    # every child lies inside its parent's time (a group's first profile
+    # step runs at admission, with its transfer in ``sched.admit.sync``)
     for child, parent in [("sched.blocks", "sched.begin"),
                           ("sched.launch", "sched.begin"),
                           ("sched.groups", "sched.finish"),
-                          ("sched.group", "sched.groups"),
-                          ("sched.prefill", "sched.admit")]:
+                          ("sched.groups.sync", "sched.groups"),
+                          ("sched.prefill", "sched.admit"),
+                          ("sched.admit.sync", "sched.admit")]:
         assert 0 < t[child]["seconds"] <= t[parent]["seconds"]
         assert t[parent]["self_seconds"] < t[parent]["seconds"]
 
@@ -167,5 +175,6 @@ def test_spans_land_on_the_host_plane_of_a_trace(chameleon, tmp_path):
     names = [e.name for pl in ProfileData.from_file(path).planes
              if pl.name.startswith("/host:")
              for ln in pl.lines for e in ln.events]
-    assert names.count("sched.group.sync") == 2 * 2
+    assert names.count("sched.sync") == 2
+    assert names.count("sched.groups") == 2 * 2  # dispatch and commit
     assert names.count("sched.step") == names.count("sched.finish") == 2
