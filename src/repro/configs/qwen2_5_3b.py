@@ -1,6 +1,7 @@
-"""Qwen2.5-3B [hf:Qwen/Qwen2.5 family] — dense GQA with QKV bias.
+"""Qwen2.5-3B [hf:Qwen/Qwen2.5-3B config.json] — dense GQA with QKV bias.
 
-36L d_model=2048 16H (GQA kv=2) d_ff=11008 vocab=151936.
+36L d_model=2048 16H (GQA kv=2) d_ff=11008 vocab=151936, tied embeddings,
+RMSNorm eps 1e-6, RoPE theta 1e6.
 """
 from repro.configs.base import ModelConfig
 
@@ -14,7 +15,9 @@ CONFIG = ModelConfig(
     d_ff=11008,
     vocab_size=151_936,
     rope_theta=1_000_000.0,
+    rmsnorm_eps=1e-6,
     qkv_bias=True,
+    tie_embeddings=True,
 )
 
 SMOKE = CONFIG.replace(

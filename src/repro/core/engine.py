@@ -56,7 +56,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.hotpath import hot_path
-from repro.core import kv_cache, profiles, sampling
+from repro.core import kv_cache, prefill as prefill_plan, profiles, sampling
+from repro.models import attention
 from repro.models.registry import Model
 
 
@@ -95,8 +96,10 @@ def decode_step(model: Model, params, cache, token):
     return logits[:, 0], cache
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def mixed_step(model: Model, params, cache, tokens, t_new, lengths):
+@functools.partial(jax.jit, static_argnums=(0, 6),
+                   static_argnames=("prompt_len",), donate_argnums=(2,))
+def mixed_step(model: Model, params, cache, tokens, t_new, lengths,
+               prompt_len: Optional[int] = None):
     """One token-budget mixed prefill/decode step over the whole pool
     (chunked prefill, paged caches only): tokens [B, C] carries each slot's
     lane payload — lane 0 holds a decoding slot's last token (t_new = 1), a
@@ -110,14 +113,31 @@ def mixed_step(model: Model, params, cache, tokens, t_new, lengths):
     dispatch ever runs between steps. Returns the logits at each slot's
     LAST valid lane [B, V] (a decode slot's next-token logits; a slot
     finishing its prefill reads its first-token logits here) plus the
-    donated cache. ONE compiled executable per (B, C) signature —
-    admission rides the pool-wide step instead of stalling it."""
+    donated cache. ONE compiled executable per (B, C, prompt_len)
+    signature — admission rides the pool-wide step instead of stalling it.
+
+    The step computes packed lanes, not ``B x C``: every row's lane 0 and
+    the chunk rows' further lanes, ``B + C`` in all
+    (``attention.mixed_pack``). ``prompt_len`` is the longest prompt the
+    pool admits (its ``pad_to``): a chunk's keys lie in the slot's first
+    ``ceil(prompt_len / block_size)`` blocks, and at most
+    ``prefill.max_chunks(C, prompt_len)`` rows carry two or more lanes (the
+    packer's bound). Unset, it is the whole block table."""
+    b, c = tokens.shape
+    bt = cache["block_tables"]
+    bs = jax.tree.leaves(cache["layers"][0])[0].shape[1]
+    mb = bt.shape[1]
+    prompt_len = min(prompt_len or mb * bs, mb * bs)
+    pack = attention.mixed_pack(
+        t_new, lengths, bt, width=c, block_size=bs,
+        chunk_rows=min(b, prefill_plan.max_chunks(c, prompt_len)),
+        prompt_blocks=-(-prompt_len // bs),
+    )
     cache = {**cache, "lengths": lengths}
     logits, cache, _ = model.forward(
-        params, {"tokens": tokens, "t_new": t_new}, cache=cache, mode="mixed"
+        params, {"tokens": tokens, "t_new": t_new, "pack": pack},
+        cache=cache, mode="mixed",
     )
-    # mixed-mode forward already gathered each slot's last valid lane
-    # before the unembed (the vocab projection runs on one lane per slot)
     return logits[:, 0], cache
 
 
@@ -211,14 +231,15 @@ def tp_decode_step(model: Model, params, cache, token, *, shardings):
             _tp_constrain(cache, shardings))
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("shardings",), donate_argnums=(2,))
-def tp_mixed_step(model: Model, params, cache, tokens, t_new, lengths, *,
-                  shardings):
+@functools.partial(jax.jit, static_argnums=(0, 6),
+                   static_argnames=("shardings", "prompt_len"),
+                   donate_argnums=(2,))
+def tp_mixed_step(model: Model, params, cache, tokens, t_new, lengths,
+                  prompt_len: Optional[int] = None, *, shardings):
     """``mixed_step`` on the mesh (cache donated shard-for-shard)."""
     with _on_mesh(shardings):
         logits, cache = mixed_step(model, params, cache, tokens, t_new,
-                                   lengths)
+                                   lengths, prompt_len=prompt_len)
     return (_tp_replicated(logits, shardings),
             _tp_constrain(cache, shardings))
 

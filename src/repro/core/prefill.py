@@ -19,7 +19,8 @@ packer's invariants are property-testable without a model:
 - :meth:`ChunkedPrefill.plan` assembles one step: decode slots get their
   last sampled token in lane 0 (``t_new = 1``); waiting cursors are walked
   FIFO and granted ``min(remaining, budget_left)`` lanes each until the
-  step's prefill budget is spent; idle rows ride with ``t_new = 0``;
+  step's prefill budget is spent or it carries :func:`max_chunks` chunks;
+  idle rows ride with ``t_new = 0``;
 - chunk spans are contiguous, disjoint, and strictly advancing — no prompt
   token is ever written twice, and a final partial chunk is *padded* to
   the lane width (``t_new`` records the true length), never dropped;
@@ -97,21 +98,35 @@ class StepPlan:
     chunks: List[Chunk] = field(default_factory=list)
 
 
+def max_chunks(budget: int, prompt_len: int) -> int:
+    """Most chunks one mixed step carries: ``ceil(budget / prompt_len) +
+    1``. A budget of ``budget`` tokens holds the tail of one prompt and
+    then whole prompts; with every prompt at the pool's longest
+    (``prompt_len``) that is never more than this many, so the bound only
+    defers the extra short prompts of a step to the next one. It is what
+    keeps the mixed step's chunk attention a static number of rows
+    (``engine.mixed_step``)."""
+    return -(-budget // prompt_len) + 1
+
+
 class ChunkedPrefill:
     """Chunk-cursor queue + token-budget packer for the mixed step.
 
     ``budget`` is both the per-step prefill-token budget and the static
     lane width of the mixed-step executable (a single cursor may take the
-    whole budget in one chunk, so the row must hold it). Cursors are kept
-    in admission order (dict insertion order): the head cursor drains
-    first, which keeps TTFT ordering close to FIFO admission.
+    whole budget in one chunk, so the row must hold it). ``prompt_len``,
+    the longest prompt the pool admits, sets how many chunks a step may
+    carry (:func:`max_chunks`). Cursors are kept in admission order (dict
+    insertion order): the head cursor drains first, which keeps TTFT
+    ordering close to FIFO admission.
     """
 
-    def __init__(self, slots: int, budget: int):
+    def __init__(self, slots: int, budget: int, prompt_len: int):
         if budget < 1:
             raise ValueError("prefill budget must be at least one token")
         self.slots = slots
         self.budget = budget
+        self.max_chunks = min(slots, max_chunks(budget, prompt_len))
         self.cursors: Dict[int, ChunkCursor] = {}  # slot -> cursor, FIFO
 
     def __len__(self) -> int:
@@ -131,9 +146,10 @@ class ChunkedPrefill:
     def plan(self, decode_tokens: np.ndarray, decode_slots: Iterable[int],
              skip: Iterable[int] = ()) -> StepPlan:
         """Pack one mixed step: decode lanes for every live slot plus up to
-        ``budget`` prefill tokens from the cursor queue (FIFO). Does NOT
-        advance cursors — the scheduler commits spans only after the step's
-        blocks are ensured and the executable has run (``advance``).
+        ``budget`` prefill tokens from at most ``max_chunks`` cursors of
+        the queue (FIFO). Does NOT advance cursors — the scheduler commits
+        spans only after the step's blocks are ensured and the executable
+        has run (``advance``).
         ``skip`` excludes cursors (by slot) whose chunks the pool cannot
         back this step, so their budget share flows to later cursors
         instead of being hoarded by a starved queue head."""
@@ -146,7 +162,7 @@ class ChunkedPrefill:
         left = self.budget
         chunks: List[Chunk] = []
         for slot, cur in self.cursors.items():
-            if left <= 0:
+            if left <= 0 or len(chunks) == self.max_chunks:
                 break
             if slot in skip:
                 continue
@@ -157,6 +173,9 @@ class ChunkedPrefill:
             t_new[slot] = t  # final partial chunk: padded lanes, true t_new
             chunks.append(Chunk(slot=slot, start=cur.pos, t=t))
             left -= t
+        # the mixed step packs the chunks' lanes past lane 0 into C lanes
+        assert sum(ch.t for ch in chunks) <= self.budget
+        assert len(chunks) <= self.max_chunks
         return StepPlan(tokens=tokens, t_new=t_new, chunks=chunks)
 
     def advance(self, chunk: Chunk) -> ChunkCursor:

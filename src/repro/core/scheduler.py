@@ -136,8 +136,9 @@ numerics decision.
 inside a named span (``self.spans``) that lands in a profiler trace as a
 ``TraceAnnotation`` and in per-name totals (``spans.totals()``).
 ``sched.step`` (an XProf step) holds ``sched.begin`` (children
-``sched.blocks``: block growth and the block-table upload;
-``sched.launch``: the jitted step call) and ``sched.finish`` (children
+``sched.blocks``: block growth and the block-table upload, holding
+``sched.plan`` in a mixed step: the chunk packer and the blocks its
+chunks need; ``sched.launch``: the jitted step call) and ``sched.finish`` (children
 ``sched.sync``: the step's one device_get; ``sched.commit``; and, with
 resident groups, ``sched.groups`` twice: the lane banks' dispatch, meta
 ``lanes``, before the sync, and the groups' commit after it, holding
@@ -152,7 +153,7 @@ import time
 import types
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -451,7 +452,7 @@ class Scheduler:
         self.chunk_mgr: Optional[ChunkedPrefill] = None
         if chunked:
             budget = prefill_budget if prefill_budget is not None else block_size
-            self.chunk_mgr = ChunkedPrefill(slots, budget)
+            self.chunk_mgr = ChunkedPrefill(slots, budget, pad_to)
         # cross-request prefix cache (host-only trie over the pool's
         # blocks; allocates ZERO device memory — reuse, not growth)
         self._pcache: Optional[PrefixCache] = (
@@ -484,6 +485,9 @@ class Scheduler:
         self.n_group_admissions = 0
         self.n_group_batches = 0  # lane-bank programs dispatched
         self.group_lane_trace: List[int] = []  # live lanes, per program
+        # per mixed step: (valid lanes = the step's real tokens, lanes the
+        # packed step computes = slots + prefill budget)
+        self.mixed_lane_trace: List[Tuple[int, int]] = []
         # speculative decoding accounting: a "spec slot-step" is one slot
         # carrying a >= 2-wide draft/verify window through one pool step;
         # the histogram keys on tokens committed by that slot that step
@@ -1422,16 +1426,17 @@ class Scheduler:
                 s for g in self.groups.values() for s in g.slots
             ]
             starved: set = set()
-            while True:
-                plan = self.chunk_mgr.plan(self._token, decode_slots,
-                                           skip=starved)
-                kept = list(plan.chunks)
-                newly = [ch.slot for ch in plan.chunks
-                         if not self._ensure_or_reclaim(ch.slot,
-                                                        ch.start + ch.t - 1)]
-                if not newly:
-                    break
-                starved.update(newly)
+            with self.spans("sched.plan"):
+                while True:
+                    plan = self.chunk_mgr.plan(self._token, decode_slots,
+                                               skip=starved)
+                    kept = list(plan.chunks)
+                    newly = [ch.slot for ch in plan.chunks
+                             if not self._ensure_or_reclaim(
+                                 ch.slot, ch.start + ch.t - 1)]
+                    if not newly:
+                        break
+                    starved.update(newly)
             self.pool.sync()
         if not kept:
             if self.active or self.groups:
@@ -1460,11 +1465,14 @@ class Scheduler:
                 base[s] = g.kv_len
         for slot, cur in self.chunk_mgr.cursors.items():
             base[slot] = cur.pos
+        self.mixed_lane_trace.append(
+            (len(decode_slots) + sum(ch.t for ch in kept),
+             self.slots + self.chunk_mgr.budget))
         with self.spans("sched.launch"):
             logits, cache = self._steps.mixed_step(
                 self.model, self.params, self.pool.cache,
                 jnp.asarray(plan.tokens), jnp.asarray(plan.t_new),
-                jnp.asarray(base),
+                jnp.asarray(base), self.pad_to,
             )
         self.pool.cache = cache
         return ("mixed", logits, kept)

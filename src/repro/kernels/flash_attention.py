@@ -40,6 +40,10 @@ _BIG = 2 ** 30  # position bound of a tile with no valid key
 #: query rows (block_q * Hq) per block: the per-row softmax state and
 #: accumulator of every head stay in scoped VMEM (16 MiB on a v5e)
 _MAX_Q_ROWS = 4096
+#: scores of one KV head's tile (block_q * G * block_k): its float32
+#: score and probability tiles sit in the same scoped VMEM, so a model with
+#: many query heads per KV head (G) takes fewer query positions a block
+_MAX_TILE_SCORES = 512 * 512
 
 
 def _flash_kernel(
@@ -147,8 +151,9 @@ def flash_attention_pallas(
     tk, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
     g = hq // hkv
     scale = scale if scale is not None else d ** -0.5
-    block_q = min(block_q, tq, max(16, _MAX_Q_ROWS // hq // 16 * 16))
     block_k = min(block_k, tk)
+    block_q = min(block_q, tq, max(16, _MAX_Q_ROWS // hq // 16 * 16),
+                  max(16, _MAX_TILE_SCORES // (g * block_k) // 16 * 16))
 
     pq = (-tq) % block_q
     pk = (-tk) % block_k

@@ -12,20 +12,24 @@ Modes:
                attends causally over the in-flight K/V.
 - ``decode``:  one token per slot; vmapped dynamic_update_slice write at
                ``lengths % cache_len``; decode attention over the cache.
-- ``mixed``:   paged caches only (chunked prefill): a per-slot chunk of
-               ``t_new[b]`` tokens (0 = idle row, 1 = plain decode) written
-               straight into the slot's blocks, then chunk-query flash
-               attention against the slot's existing paged K/V plus the
-               chunk itself (intra-chunk causal via query positions).
-- ``verify``:  speculative verification window: same per-slot-``t_new``
-               write+attend program as ``mixed`` (paged pools reuse it
-               verbatim; contiguous pools get a masked window scatter),
-               but the caller keeps EVERY lane's logits — one full-model
+- ``mixed``:   paged caches only (chunked prefill), on PACKED lanes
+               (:class:`MixedPack`): every row's lane 0 (a decode token, a
+               chunk's first token or an idle row) plus the chunk rows'
+               further lanes, ``B + C`` lanes in all. Each lane's K/V is
+               written straight into its slot's blocks; lane 0 attends
+               through the paged decode walk, the chunk lanes through
+               chunk-query flash attention over their slots' prompt blocks
+               (intra-chunk causal via the query positions).
+- ``verify``:  speculative verification window: a per-slot ``t_new``
+               window over ``[B, C]`` lanes, written into the blocks (or,
+               on contiguous pools, by a masked window scatter), then
+               window-query flash attention over each slot's gathered view;
+               the caller keeps EVERY lane's logits — one full-model
                forward scores a whole drafted window per slot.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -141,9 +145,9 @@ def paged_gather(buf: jnp.ndarray, bt: jnp.ndarray) -> jnp.ndarray:
     through bt [B, MB] -> [B, MB * bs, ...]. The gather is a transient
     activation (same read set the contiguous decode touches); the memory
     the pool *reserves* is only ``NB * bs`` tokens. Single-token decode no
-    longer pays this transient (see :func:`paged_decode_attention`); it is
-    kept for the chunk-query mixed step, where the one gather is amortized
-    over a whole prefill chunk of queries, and for tests."""
+    longer pays this transient (see :func:`paged_decode_attention`); the
+    mixed step gathers only its chunk rows' prompt blocks through it, and
+    the verify window every slot's whole view."""
     b, mb = bt.shape
     g = buf[bt]  # [B, MB, bs, ...]
     return g.reshape((b, mb * buf.shape[1]) + buf.shape[2:])
@@ -206,6 +210,120 @@ def paged_decode_attention(
 
     accs, ms, ls = jax.lax.map(body, jnp.arange(mb))
     return ops.combine_partial_attention(accs, ms, ls).astype(q.dtype)
+
+
+class MixedPack(NamedTuple):
+    """Where the ``N = B + C`` computed lanes of a packed mixed step come
+    from, and how their attention is laid out. Lane ``b < B`` is row
+    ``b``'s lane 0: its decode token, its chunk's first token, or an idle
+    row (``t_new = 0``). Lanes ``B ..`` hold the chunk rows' lanes
+    ``1 .. t_new - 1``, row after row; past their total they are padding.
+    Chunk attention runs on ``K`` rows, one per chunk row (``t_new >= 2``),
+    each holding that chunk's queries in its first lanes and the first
+    ``P`` blocks of the slot's table as its keys."""
+
+    row: jnp.ndarray  # [N] source row of each lane
+    col: jnp.ndarray  # [N] lane within that row
+    pos: jnp.ndarray  # [N] logical position the lane writes and attends at
+    phys: jnp.ndarray  # [N] physical block of its write (0 = the sink)
+    last: jnp.ndarray  # [B] lane that holds each row's last valid token
+    n_valid: jnp.ndarray  # [B] keys each lane 0 attends
+    tables: jnp.ndarray  # [K, P] the chunk rows' prompt blocks
+    q_lane: jnp.ndarray  # [K, C] lane of each chunk query
+    q_pos: jnp.ndarray  # [K, C] its position (-1: padding, sees no key)
+    k_valid: jnp.ndarray  # [K, P * bs] keys written by the end of the step
+    out_at: jnp.ndarray  # [C] index into the [K * C] chunk outputs
+
+
+def mixed_pack(t_new: jnp.ndarray, lengths: jnp.ndarray, bt: jnp.ndarray,
+               *, width: int, block_size: int, chunk_rows: int,
+               prompt_blocks: int) -> MixedPack:
+    """The lane layout of one mixed step from its per-row widths ``t_new``
+    [B] and write positions ``lengths`` [B]: ``width`` (C) is the step's
+    prefill budget, which bounds the chunk lanes' total; ``chunk_rows``
+    (K) bounds the rows with ``t_new >= 2`` and ``prompt_blocks`` (P) the
+    blocks a chunk's keys can lie in (a chunk belongs to a prompt, and
+    prompts fill a slot's first blocks). The packer
+    (core/prefill.ChunkedPrefill.plan) guarantees all three."""
+    b, c, bs = t_new.shape[0], width, block_size
+    mb = bt.shape[1]
+    i32 = jnp.int32
+    extra = jnp.maximum(t_new - 1, 0)  # a row's lanes past lane 0
+    ends = jnp.cumsum(extra)
+    starts = ends - extra
+    lane = jnp.arange(c, dtype=i32)
+    # the row of chunk lane i: the first row whose lanes end past i
+    src = jnp.minimum(jnp.sum(ends[None, :] <= lane[:, None], axis=1), b - 1)
+    live = lane < ends[-1]
+    ccol = jnp.where(live, lane - starts[src] + 1, 0)
+    row = jnp.concatenate([jnp.arange(b, dtype=i32), src.astype(i32)])
+    col = jnp.concatenate([jnp.zeros((b,), i32), ccol.astype(i32)])
+    valid = jnp.concatenate([t_new > 0, live])
+    pos = lengths[row] + col
+    blk = jnp.clip(pos // bs, 0, mb - 1)
+    phys = jnp.where(valid, bt[row, blk], 0)
+    last = jnp.where(t_new > 1, b + ends - 1, jnp.arange(b)).astype(i32)
+    is_chunk = t_new > 1
+    crow = jnp.nonzero(is_chunk, size=chunk_rows, fill_value=0)[0]
+    used = jnp.arange(chunk_rows) < jnp.sum(is_chunk)
+    q_ok = used[:, None] & (lane[None, :] < extra[crow][:, None])
+    q_lane = jnp.where(q_ok, b + starts[crow][:, None] + lane[None, :], 0)
+    q_pos = jnp.where(q_ok, lengths[crow][:, None] + 1 + lane[None, :], -1)
+    keys = jnp.arange(prompt_blocks * bs)
+    k_valid = used[:, None] & (keys[None, :]
+                               < (lengths + t_new)[crow][:, None])
+    rank = jnp.cumsum(is_chunk) - 1  # a chunk row's attention row
+    out_at = jnp.where(live, rank[src] * c + ccol - 1, 0)
+    return MixedPack(
+        row=row, col=col, pos=pos, phys=phys, last=last,
+        n_valid=valid_counts(lengths + 1, mb * bs),
+        tables=bt[crow, :prompt_blocks], q_lane=q_lane.astype(i32),
+        q_pos=q_pos.astype(i32), k_valid=k_valid, out_at=out_at.astype(i32),
+    )
+
+
+@hot_path
+def paged_write_lanes(buf: jnp.ndarray, new: jnp.ndarray,
+                      pack: MixedPack) -> jnp.ndarray:
+    """Scatter the packed lanes' K/V [N, ...] into the block pool at their
+    physical positions; padding lanes and idle rows land in the sink
+    block 0. Valid lanes are distinct (slot, position) pairs in blocks
+    their slots own, so they never collide."""
+    return buf.at[pack.phys, pack.pos % buf.shape[1]].set(new.astype(buf.dtype))
+
+
+@hot_path
+def paged_mixed_attention(
+    q: jnp.ndarray,  # [N, Hq, D] packed lanes
+    kbuf: jnp.ndarray,  # [NB, bs, Hkv, D] or [NB, bs, D] (shared-head latent)
+    vbuf: Optional[jnp.ndarray],  # like kbuf, or None: V = K[..., :v_dim]
+    bt: jnp.ndarray,  # [B, MB] block table
+    pack: MixedPack,
+    *,
+    scale: Optional[float] = None,
+    v_dim: Optional[int] = None,
+    impl: str = "auto",
+) -> jnp.ndarray:
+    """Attention of a packed mixed step, after the step's writes. Lane 0
+    of each row is one query at ``lengths`` over ``lengths + 1`` keys: the
+    paged decode walk. The chunk lanes attend their slot's prompt blocks
+    only, one flash-attention row per chunk row. Returns [N, Hq, Dv]."""
+    b = bt.shape[0]
+    head = paged_decode_attention(q[:b], kbuf, vbuf, bt, pack.n_valid,
+                                  scale=scale, v_dim=v_dim)
+    kc = paged_gather(kbuf, pack.tables)  # [K, P * bs, ...]
+    vc = paged_gather(vbuf, pack.tables) if vbuf is not None \
+        else kc[..., :v_dim]
+    if kc.ndim == 3:  # shared-head latent: add the Hkv=1 axis
+        kc, vc = kc[:, :, None, :], vc[:, :, None, :]
+    k_rows, s = pack.k_valid.shape
+    out = ops.flash_attention(
+        q[pack.q_lane], kc, vc, q_positions=pack.q_pos,
+        k_positions=jnp.broadcast_to(jnp.arange(s)[None], (k_rows, s)),
+        causal=True, k_valid=pack.k_valid, scale=scale, impl=impl,
+    )  # [K, C, Hq, Dv]
+    tail = out.reshape((-1,) + out.shape[2:])[pack.out_at]
+    return jnp.concatenate([head, tail.astype(head.dtype)])
 
 
 def _sp_decode(cache, k_new, v_new, q, lengths):
@@ -303,7 +421,8 @@ def attention(
     window: Optional[int] = None,
     impl: str = "auto",
     bidirectional: bool = False,
-    t_new: Optional[jnp.ndarray] = None,  # [B] chunk widths (mixed mode)
+    t_new: Optional[jnp.ndarray] = None,  # [B] window widths (verify mode)
+    pack: Optional[MixedPack] = None,  # lane layout (mixed mode)
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     b, t, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -352,14 +471,22 @@ def attention(
             out = paged_decode_attention(
                 q[:, 0], new_cache["k"], new_cache["v"], bt, n_valid,
             )[:, None]
+        elif mode == "mixed":
+            # packed lanes [N, 1]: every lane's K/V lands before any lane
+            # attends, so a chunk sees its own earlier tokens
+            new_cache = {
+                "k": paged_write_lanes(cache["k"], k[:, 0], pack),
+                "v": paged_write_lanes(cache["v"], v[:, 0], pack),
+                "bt": bt,
+            }
+            out = paged_mixed_attention(
+                q[:, 0], new_cache["k"], new_cache["v"], bt, pack, impl=impl,
+            )[:, None]
         else:
-            # mixed step: write each slot's chunk (decode rows are width-1
-            # chunks) into its blocks, then chunk-query flash attention over
-            # the slot's gathered logical view — prior blocks AND the chunk
-            # just written, intra-chunk causality via the query positions.
-            # Verify mode is the same device program over a speculative
-            # window (per-slot t_new = window width); only the caller
-            # differs — it keeps every lane's logits instead of the last.
+            # verify window: write each slot's window into its blocks, then
+            # window-query flash attention over the slot's gathered logical
+            # view — prior blocks AND the window just written, intra-window
+            # causality via the query positions.
             new_cache = {
                 "k": paged_write_chunk(cache["k"], k, bt, lengths, t_new),
                 "v": paged_write_chunk(cache["v"], v, bt, lengths, t_new),
@@ -516,7 +643,8 @@ def mla_attention(
     cache: Optional[dict],
     mode: str,
     impl: str = "auto",
-    t_new: Optional[jnp.ndarray] = None,  # [B] chunk widths (mixed mode)
+    t_new: Optional[jnp.ndarray] = None,  # [B] window widths (verify mode)
+    pack: Optional[MixedPack] = None,  # lane layout (mixed mode)
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     m = cfg.mla
     b, t, _ = x.shape
@@ -559,7 +687,15 @@ def mla_attention(
                 "bt": bt,
             }
             s = bt.shape[1] * cache["latent"].shape[1]  # logical view length
-        elif paged:  # mixed/verify: per-slot latent chunk into the blocks
+        elif paged and mode == "mixed":  # packed lanes into the blocks
+            bt = cache["bt"]
+            new_cache = {
+                "latent": paged_write_lanes(
+                    cache["latent"], latent_new[:, 0], pack
+                ),
+                "bt": bt,
+            }
+        elif paged:  # verify: per-slot latent window into the blocks
             bt = cache["bt"]
             new_cache = {
                 "latent": paged_write_chunk(
@@ -605,6 +741,11 @@ def mla_attention(
                 q_eff[:, 0], new_cache["latent"], None, bt, n_valid,
                 scale=scale, v_dim=m.kv_lora_rank,
             )[:, None]  # [B,1,H,r]
+        elif mode == "mixed":
+            ctx_lat = paged_mixed_attention(
+                q_eff[:, 0], new_cache["latent"], None, bt, pack,
+                scale=scale, v_dim=m.kv_lora_rank, impl=impl,
+            )[:, None]  # [N,1,H,r]
         elif mode == "decode":
             k_eff = lat  # K = whole latent buffer (no copy)
             v_eff = lat[:, :, : m.kv_lora_rank]  # V = slice
@@ -613,10 +754,10 @@ def mla_attention(
                 q_eff[:, 0], k_eff[:, :, None, :], v_eff[:, :, None, :],
                 n_valid, scale=scale, impl=impl,
             )[:, None]  # [B,1,H,r]
-        else:  # extend / mixed: chunk-query flash over the logical view
-            k_eff = lat  # paged mixed: gathered view (amortized over chunk)
+        else:  # extend / verify: window-query flash over the logical view
+            k_eff = lat  # paged verify: gathered view (amortized over window)
             v_eff = lat[:, :, : m.kv_lora_rank]  # V = slice
-            ext = t_new if mode in ("mixed", "verify") else t  # per-slot or uniform
+            ext = t_new if mode == "verify" else t  # per-slot or uniform
             kpos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
             k_valid = jnp.arange(s)[None] < (lengths + ext)[:, None]
             ctx_lat = ops.flash_attention(

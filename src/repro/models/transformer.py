@@ -59,10 +59,11 @@ def layer_forward(
     mode: str,
     impl: str = "auto",
     t_new: Optional[jnp.ndarray] = None,
+    pack: Optional[A.MixedPack] = None,
 ) -> Tuple[jnp.ndarray, Optional[dict], jnp.ndarray]:
     h = L.rmsnorm(p["attn_norm"], x, cfg.rmsnorm_eps)
     kw = dict(positions=positions, lengths=lengths, cache=cache, mode=mode,
-              impl=impl, t_new=t_new)
+              impl=impl, t_new=t_new, pack=pack)
     if cfg.mla is not None:
         attn_out, new_cache = A.mla_attention(cfg, p["attn"], h, **kw)
     else:
@@ -140,7 +141,8 @@ def forward(
     mode: str = "train",
     impl: str = "auto",
 ) -> Tuple[jnp.ndarray, Optional[dict], dict]:
-    """Returns (logits [B,T,V] f32, new_cache, aux dict)."""
+    """Returns (logits [B,T,V] f32, new_cache, aux dict); a mixed step
+    returns each row's last valid lane only, [B,1,V]."""
     tokens = batch["tokens"]
     b, t = tokens.shape
     if mode == "train":
@@ -153,6 +155,11 @@ def forward(
     # widths [B]; lanes beyond t_new[b] are padding (writes hit the sink
     # block / get dropped, outputs discarded)
     t_new = batch.get("t_new") if mode in ("mixed", "verify") else None
+    pack = batch["pack"] if mode == "mixed" else None
+    if pack is not None:
+        # the step's real lanes only, as [N, 1] rows (attention.MixedPack)
+        tokens = tokens[pack.row, pack.col][:, None]
+        positions = pack.pos[:, None]
 
     x = L.embed(params["embed"], tokens)
     aux_total = jnp.float32(0.0)
@@ -178,7 +185,7 @@ def forward(
             lc = dict(lc, bt=bt)
         x, nlc, aux = layer_fn(
             cfg, lp, x, layer=i, positions=positions, lengths=lengths,
-            cache=lc, mode=mode, impl=impl, t_new=t_new,
+            cache=lc, mode=mode, impl=impl, t_new=t_new, pack=pack,
         )
         if bt is not None and nlc is not None:
             nlc = {k: v for k, v in nlc.items() if k != "bt"}
@@ -203,13 +210,12 @@ def forward(
             body, (x, aux_total), (params["scanned"], scanned_cache)
         )
 
-    if mode == "mixed":
+    if pack is not None:
         # only each slot's LAST valid lane is ever read (a decode slot's
         # next-token logits / a finishing prefill's first-token logits):
         # gather it before the unembed so the vocab projection runs on one
-        # lane per slot, not the whole chunk width
-        idx = jnp.maximum(t_new - 1, 0)
-        x = jnp.take_along_axis(x, idx[:, None, None], axis=1)  # [B, 1, d]
+        # lane per slot
+        x = x[pack.last]  # [B, 1, d]
     x = L.rmsnorm(params["final_norm"], x, cfg.rmsnorm_eps)
     if cfg.tie_embeddings:
         logits = L.unembed(params["embed"], x)

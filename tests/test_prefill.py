@@ -65,7 +65,7 @@ def _req(rng, cfg, rid, size, max_new, **kw):
 def _drain(slots, budget, prompt_lens, seed):
     """Drive plan/advance to completion; check every packer invariant."""
     rng = np.random.default_rng(seed)
-    mgr = ChunkedPrefill(slots, budget)
+    mgr = ChunkedPrefill(slots, budget, prompt_len=max(prompt_lens))
     prompts = {}
     for i, n in enumerate(prompt_lens):
         prompts[i] = rng.integers(1, 1000, size=n).astype(np.int32)
@@ -77,6 +77,7 @@ def _drain(slots, budget, prompt_lens, seed):
         plan = mgr.plan(decode_tokens, decode_slots=[])
         assert plan.tokens.shape == (slots, budget)
         assert sum(ch.t for ch in plan.chunks) <= budget, "budget exceeded"
+        assert len(plan.chunks) <= mgr.max_chunks, "too many chunks"
         assert plan.chunks, "pending cursors but an empty plan (livelock)"
         for ch in plan.chunks:
             cur = mgr.cursors[ch.slot]
@@ -128,7 +129,7 @@ def test_packer_property(budget, prompt_lens, seed):
 def test_packer_interleaves_decode_lanes():
     """Decode slots keep lane 0 (t_new=1) while a cursor's chunk shares the
     same step — and the cursor never steals a decode slot's row."""
-    mgr = ChunkedPrefill(3, 4)
+    mgr = ChunkedPrefill(3, 4, prompt_len=8)
     mgr.add(ChunkCursor(req=None, slot=1, prompt=np.arange(1, 7), admit_seq=0))
     decode_tokens = np.asarray([7, 0, 9], np.int32)
     plan = mgr.plan(decode_tokens, decode_slots=[0, 2])
@@ -141,7 +142,7 @@ def test_packer_interleaves_decode_lanes():
 def test_packer_skip_redistributes_budget():
     """A block-starved head cursor (skip) must not hoard the step budget:
     its share flows to the next cursor in FIFO order."""
-    mgr = ChunkedPrefill(3, 4)
+    mgr = ChunkedPrefill(3, 4, prompt_len=8)
     mgr.add(ChunkCursor(req=None, slot=0, prompt=np.arange(1, 9), admit_seq=0))
     mgr.add(ChunkCursor(req=None, slot=2, prompt=np.arange(1, 7), admit_seq=1))
     plan = mgr.plan(np.zeros((3,), np.int32), decode_slots=[], skip=[0])

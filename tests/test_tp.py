@@ -321,3 +321,61 @@ def test_tp2_subprocess_parity():
     )
     assert r.returncode == 0, r.stdout + r.stderr
     assert "TP2_PARITY_OK" in r.stdout
+
+
+_TP2_MIXED_SCRIPT = """
+import jax
+import jax.numpy as jnp
+import numpy as np
+assert jax.device_count() == 2, jax.device_count()
+from repro.configs import qwen2_5_3b
+from repro.core import engine
+from repro.core.slot_pool import BlockPool
+from repro.distributed import tp_pool
+from repro.models import get_model
+
+model = get_model(qwen2_5_3b.SMOKE.replace(dtype="float32"))
+params = model.init(jax.random.PRNGKey(0))
+pool = BlockPool(model, 4, 25, block_size=4)
+for s in range(4):
+    assert pool.acquire() == s and pool.ensure(s, 23)
+pool.sync()
+leaves, tree = jax.tree_util.tree_flatten(pool.cache["layers"])
+keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+pool.cache["layers"] = jax.tree_util.tree_unflatten(
+    tree, [jax.random.normal(k, x.shape, x.dtype) for k, x in zip(keys, leaves)])
+copy = lambda: jax.tree.map(jnp.copy, pool.cache)
+# three chunks beside a decode row, at the packer's bound for prompts of 4
+toks = jnp.asarray(np.random.default_rng(2).integers(0, 512, (4, 8)), jnp.int32)
+t_new = jnp.asarray([2, 2, 3, 1], jnp.int32)
+lengths = jnp.asarray([0, 2, 0, 6], jnp.int32)
+want, want_cache = engine.mixed_step(model, params, copy(), toks, t_new,
+                                     lengths, 4)
+ctx = tp_pool.TPContext(model, params, tp_pool.make_tp_mesh(2),
+                        cache_like=pool.cache, max_len=25)
+got, got_cache = ctx.executables().mixed_step(
+    model, ctx.params, ctx.place_cache(copy()), toks, t_new, lengths, 4)
+np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                           atol=1e-4)
+for g, w in zip(jax.tree.leaves(got_cache["layers"]),
+                jax.tree.leaves(want_cache["layers"])):
+    np.testing.assert_allclose(np.asarray(g)[1:], np.asarray(w)[1:],
+                               rtol=1e-5, atol=1e-5)
+print("TP2_MIXED_OK")
+"""
+
+
+def test_tp2_packed_mixed_step_matches_one_device():
+    """The packed mixed step on a 2-device TP mesh (heads split) gives the
+    one-device step's logits and K/V, with three chunks beside a decode
+    row."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    r = subprocess.run(
+        [sys.executable, "-c", _TP2_MIXED_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "TP2_MIXED_OK" in r.stdout

@@ -9,9 +9,10 @@ Llama-3.2-1B's widths (32 query heads, 8 KV heads, head dim 64, bf16) and
 assert that the compiled program holds the kernel (``tpu_custom_call``).
 
 Shapes: a prefill, a chunked-prefill mixed step (16 queries against 64
-blocks of 16 gathered keys), contiguous decode (8 slots, 1024 cached
-tokens), and both kernels on a 4-chip tensor-parallel mesh, where the
-kernels run under shard_map on each chip's own heads
+blocks of 16 gathered keys), the packed mixed step's chunk rows at
+Qwen2.5-3B's heads (16 query heads per 2 KV heads), contiguous decode (8
+slots, 1024 cached tokens), and both kernels on a 4-chip tensor-parallel
+mesh, where the kernels run under shard_map on each chip's own heads
 (kernels/ops.py ``_on_own_heads``).
 
 The topology is described inside a fixture, never at import time: only
@@ -60,14 +61,22 @@ def _compiled_text(fn, *args):
 
 
 @pytest.mark.parametrize(
-    "b,tq,tk",
+    "b,tq,tk,heads",
     [
-        (1, 256, 256),  # prefill of a 256-token prompt
-        (8, 16, 64 * 16),  # mixed step: 16-token chunk over 64 blocks of 16
+        (1, 256, 256, (HQ, HKV, D)),  # prefill of a 256-token prompt
+        # mixed step: 16-token chunk over 64 blocks of 16
+        (8, 16, 64 * 16, (HQ, HKV, D)),
+        # the packed mixed step's chunk rows at Qwen2.5-3B's heads (16
+        # query heads per 2 KV heads of 128): 3 rows of 512 queries over
+        # the 27 prompt blocks, and 2 rows over a whole 652-block table
+        (3, 512, 27 * 16, (16, 2, 128)),
+        (2, 512, 652 * 16, (16, 2, 128)),
     ],
-    ids=["prefill", "chunk"],
+    ids=["prefill", "chunk", "packed_chunk_rows", "packed_whole_table"],
 )
-def test_flash_attention_compiles_for_v5e(one_chip, b, tq, tk):
+def test_flash_attention_compiles_for_v5e(one_chip, b, tq, tk, heads):
+    hq, hkv, d = heads
+
     def s(shape, dtype=BF16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -78,7 +87,7 @@ def test_flash_attention_compiles_for_v5e(one_chip, b, tq, tk):
         )
 
     text = _compiled_text(
-        fn, s((b, tq, HQ, D)), s((b, tk, HKV, D)), s((b, tk, HKV, D)),
+        fn, s((b, tq, hq, d)), s((b, tk, hkv, d)), s((b, tk, hkv, d)),
         s((b, tq), I32), s((b, tk), I32), s((b, tk), jnp.bool_),
     )
     assert "tpu_custom_call" in text

@@ -178,3 +178,28 @@ def test_spans_land_on_the_host_plane_of_a_trace(chameleon, tmp_path):
     assert names.count("sched.sync") == 2
     assert names.count("sched.groups") == 2 * 2  # dispatch and commit
     assert names.count("sched.step") == names.count("sched.finish") == 2
+
+
+def test_chunked_run_times_the_packer_and_counts_its_lanes():
+    """A chunked run of greedy requests: one ``sched.plan`` (inside
+    ``sched.blocks``) a mixed step, and a ``mixed_lane_trace`` entry of
+    (real tokens, slots + budget) each; a decode step plans nothing."""
+    from repro.configs import qwen2_5_3b
+
+    model = get_model(qwen2_5_3b.SMOKE.replace(dtype="float32"))
+    sched = Scheduler(model, model.init(KEY), slots=4, pad_to=24,
+                      max_new_cap=6, paged=True, chunked=True, block_size=4,
+                      prefill_budget=8)
+    rng = np.random.default_rng(2)
+    reqs = [ServeRequest(rid=i, prompt=rng.integers(0, 512, size=n),
+                         max_new=6) for i, n in enumerate([20, 5, 11, 3])]
+    sched.run(reqs)
+    t = sched.spans.totals()
+    n_mixed = sched.n_mixed_steps
+    assert 0 < n_mixed < sched.n_decode_steps
+    assert t["sched.plan"]["count"] == n_mixed
+    assert 0 < t["sched.plan"]["seconds"] <= t["sched.blocks"]["seconds"]
+    assert len(sched.mixed_lane_trace) == n_mixed
+    chunk_tokens = sum(v for v, _ in sched.mixed_lane_trace)
+    assert chunk_tokens >= sum(len(r.prompt) for r in reqs)
+    assert all(c == 4 + 8 for _, c in sched.mixed_lane_trace)
