@@ -165,6 +165,7 @@ from repro.core.prefill import ChunkCursor, ChunkedPrefill
 from repro.core.prefix_cache import PrefixCache
 from repro.core.slot_pool import BlockPool, SlotPool
 from repro.core.tracing import Spans
+from repro.models import attention
 from repro.models.registry import Model
 
 
@@ -488,6 +489,9 @@ class Scheduler:
         # per mixed step: (valid lanes = the step's real tokens, lanes the
         # packed step computes = slots + prefill budget)
         self.mixed_lane_trace: List[Tuple[int, int]] = []
+        # per paged decode or mixed step: (tiles the paged decode walk
+        # runs, tiles of the whole block table)
+        self.walk_trace: List[Tuple[int, int]] = []
         # speculative decoding accounting: a "spec slot-step" is one slot
         # carrying a >= 2-wide draft/verify window through one pool step;
         # the histogram keys on tokens committed by that slot that step
@@ -1390,6 +1394,7 @@ class Scheduler:
                 self._ensure_blocks()
                 if not self.active and not self.groups:
                     return None  # everything preempted back to the queue
+                self._record_walk(self._positions())
             self.pool.sync()
         with self.spans("sched.launch"):
             logits, cache = self._steps.decode_step(
@@ -1457,14 +1462,8 @@ class Scheduler:
         # decode steps drift the device counters of free and mid-prefill
         # rows (every row increments), so the mixed step pins them — inside
         # its own executable, no resync dispatch — before any write
-        base = np.zeros((self.slots,), np.int32)
-        for slot, st in self.active.items():
-            base[slot] = st.kv_len
-        for g in self.groups.values():
-            for s in g.slots:
-                base[s] = g.kv_len
-        for slot, cur in self.chunk_mgr.cursors.items():
-            base[slot] = cur.pos
+        base = self._positions()
+        self._record_walk(base)
         self.mixed_lane_trace.append(
             (len(decode_slots) + sum(ch.t for ch in kept),
              self.slots + self.chunk_mgr.budget))
@@ -1476,6 +1475,37 @@ class Scheduler:
             )
         self.pool.cache = cache
         return ("mixed", logits, kept)
+
+    def _positions(self) -> np.ndarray:
+        """Each row's write position from host state [slots]: a decoding
+        slot's or group stream's kv length, a prefilling slot's chunk
+        cursor, 0 for a free row."""
+        base = np.zeros((self.slots,), np.int32)
+        for slot, st in self.active.items():
+            base[slot] = st.kv_len
+        for g in self.groups.values():
+            for s in g.slots:
+                base[s] = g.kv_len
+        if self.chunk_mgr is not None:
+            for slot, cur in self.chunk_mgr.cursors.items():
+                base[slot] = cur.pos
+        return base
+
+    def _record_walk(self, positions: np.ndarray) -> None:
+        """One ``walk_trace`` entry for a paged step about to launch: the
+        device's rule (``attention.walk_tiles``) on the host's positions
+        and the block table the step reads, with no device sync. Exact for
+        mixed steps, which pin every row's device counter to
+        ``positions``, and for decode steps of decoding slots; a mid-prefill
+        row in a decode step (taken only when every pending chunk is
+        block-starved) has a device counter that drifted past its cursor,
+        so the device may walk more tiles than recorded there."""
+        bt, bs = self.pool.block_tables, self.pool.block_size
+        mb = bt.shape[1]
+        n_valid = np.minimum(positions + 1, mb * bs)
+        walked = attention.walk_tiles(n_valid, bt[:, 0] != 0, bs, mb)
+        table = -(-mb // attention.walk_tile_blocks(bs, mb))
+        self.walk_trace.append((int(walked), table))
 
     @hot_path
     def _finish_mixed(self, logits, kept) -> List[ServeRequest]:

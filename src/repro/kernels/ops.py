@@ -393,6 +393,17 @@ def decode_attention_partial(
     )
 
 
+def merge_partial_attention(a, b):
+    """LSE-merge two flash-decode partials (acc, m, l) into one, still
+    unnormalized: the running form of :func:`combine_partial_attention`
+    (the paged decode walk folds each tile into its carry with it)."""
+    acc_a, m_a, l_a = a
+    acc_b, m_b, l_b = b
+    m = jnp.maximum(m_a, m_b)
+    sa, sb = jnp.exp(m_a - m), jnp.exp(m_b - m)
+    return acc_a * sa[..., None] + acc_b * sb[..., None], m, l_a * sa + l_b * sb
+
+
 def combine_partial_attention(accs, ms, ls):
     """LSE-combine flash-decode partials stacked on a leading shard axis."""
     m = ms.max(axis=0)

@@ -144,10 +144,10 @@ def paged_gather(buf: jnp.ndarray, bt: jnp.ndarray) -> jnp.ndarray:
     """Materialize each slot's logical K/V view: buf [NB, bs, ...] gathered
     through bt [B, MB] -> [B, MB * bs, ...]. The gather is a transient
     activation (same read set the contiguous decode touches); the memory
-    the pool *reserves* is only ``NB * bs`` tokens. Single-token decode no
-    longer pays this transient (see :func:`paged_decode_attention`); the
-    mixed step gathers only its chunk rows' prompt blocks through it, and
-    the verify window every slot's whole view."""
+    the pool *reserves* is only ``NB * bs`` tokens. Single-token decode
+    gathers one tile of blocks at a time through it (see
+    :func:`paged_decode_attention`); the mixed step gathers only its chunk
+    rows' prompt blocks, and the verify window every slot's whole view."""
     b, mb = bt.shape
     g = buf[bt]  # [B, MB, bs, ...]
     return g.reshape((b, mb * buf.shape[1]) + buf.shape[2:])
@@ -177,6 +177,31 @@ def paged_write_chunk(buf: jnp.ndarray, new: jnp.ndarray, bt: jnp.ndarray,
     return buf.at[phys, pos % bs].set(new.astype(buf.dtype))
 
 
+#: Most keys a slot's tile holds in one iteration of the paged decode
+#: walk: the key block of ``decode_attention_pallas``, 32 blocks of 16.
+TILE_KEYS = 512
+
+
+def walk_tile_blocks(block_size: int, max_blocks: int) -> int:
+    """Blocks a slot's tile gathers in one iteration of the decode walk:
+    the table cut into the fewest tiles of at most ``TILE_KEYS`` keys, as
+    even as they come, so a full slot's last tile is not mostly sink."""
+    n = -(-max_blocks // max(1, TILE_KEYS // block_size))
+    return -(-max_blocks // n)
+
+
+def walk_tiles(n_valid, live, block_size: int, max_blocks: int):
+    """Tiles the paged decode walk runs: enough to cover the deepest LIVE
+    row's keys ``n_valid`` [B]. ``live`` [B] is ``bt[:, 0] != 0``: the pool
+    never hands out the sink block 0 and zeroes a freed row, whose device
+    counter drifts upward every step until the slot is reassigned, so a
+    free row never sets the bound. Plain arithmetic, so the scheduler
+    applies the same rule to its host mirrors (numpy) as the step does on
+    the device (jnp)."""
+    blocks = (n_valid + block_size - 1) // block_size * live
+    return -(-blocks.max() // walk_tile_blocks(block_size, max_blocks))
+
+
 @hot_path
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, Hq, D]
@@ -188,28 +213,44 @@ def paged_decode_attention(
     scale: Optional[float] = None,
     v_dim: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Flash-decode straight off the physical block pool: one logical block
-    per step, gathered per-(slot, block) as a [B, bs, ...] scratch that the
-    scan reuses — the full [B, MB * bs, ...] per-layer transient the old
-    ``paged_gather`` decode materialized never exists. Indexing is pure
-    gather (``buf[phys]``), no ``dynamic_slice``; per-block partials are
-    LSE-combined exactly like the sequence-parallel decode path.
-    ``vbuf=None`` with ``v_dim`` serves MLA's absorbed latent, where V is
-    the leading slice of the cached K."""
+    """Flash-decode straight off the physical block pool, walking only the
+    live part of the table: a loop over tiles of ``walk_tile_blocks``
+    logical blocks, each gathered per slot as a [B, TILE_KEYS, ...]
+    scratch and folded into a running online-softmax state (acc, m, l)
+    by the LSE rule of ``ops.merge_partial_attention``. The trip count is
+    traced (``walk_tiles``: the deepest live row), so one executable
+    serves every depth. Free rows (``bt[:, 0] == 0``) are masked whole;
+    their output is zero and never read. ``vbuf=None`` with ``v_dim``
+    serves MLA's absorbed latent, where V is the leading slice of the
+    cached K."""
     bs = kbuf.shape[1]
-    mb = bt.shape[1]
+    b, mb = bt.shape
+    g = walk_tile_blocks(bs, mb)
+    # sink entries to a whole number of tiles: a clamped slice at the last
+    # tile would shift its start back and count blocks twice
+    bt = jnp.pad(bt, ((0, 0), (0, -(-mb // g) * g - mb)))
+    live = bt[:, 0] != 0
+    n_valid = jnp.where(live, n_valid, 0)
+    keys = jnp.arange(g * bs)
 
-    def body(j):
-        phys = bt[:, j]  # [B]
-        kj = kbuf[phys]  # [B, bs, ...] — the only per-block scratch
-        vj = vbuf[phys] if vbuf is not None else kj[..., :v_dim]
+    def body(j, carry):
+        phys = jax.lax.dynamic_slice_in_dim(bt, j * g, g, axis=1)  # [B, G]
+        kj = paged_gather(kbuf, phys)  # [B, G * bs, ...]
+        vj = paged_gather(vbuf, phys) if vbuf is not None else kj[..., :v_dim]
         if kj.ndim == 3:  # shared-head latent: add the Hkv=1 axis
             kj, vj = kj[:, :, None, :], vj[:, :, None, :]
-        k_valid = (j * bs + jnp.arange(bs))[None, :] < n_valid[:, None]
-        return ops.decode_attention_partial(q, kj, vj, k_valid, scale=scale)
+        k_valid = (j * g * bs + keys)[None, :] < n_valid[:, None]
+        part = ops.decode_attention_partial(q, kj, vj, k_valid, scale=scale)
+        return ops.merge_partial_attention(carry, part)
 
-    accs, ms, ls = jax.lax.map(body, jnp.arange(mb))
-    return ops.combine_partial_attention(accs, ms, ls).astype(q.dtype)
+    hq = q.shape[1]
+    dv = vbuf.shape[-1] if vbuf is not None else v_dim
+    init = (jnp.zeros((b, hq, dv), jnp.float32),
+            jnp.full((b, hq), ops.NEG_INF, jnp.float32),
+            jnp.zeros((b, hq), jnp.float32))
+    acc, _, l = jax.lax.fori_loop(0, walk_tiles(n_valid, live, bs, mb), body,
+                                  init)
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
 
 class MixedPack(NamedTuple):
