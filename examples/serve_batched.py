@@ -2,8 +2,8 @@
 the paper's HumanEval length profile through the continuous-batching
 scheduler (KV slot-pool + slot-recycling admission; core/scheduler.py).
 
-Pass ``--policy fixed`` to see the seed's run-to-completion baseline on
-the same trace — benchmarks/bench_serve.py measures that A/B properly.
+Pass ``--policy fixed`` to see the run-to-completion baseline on the same
+trace; speed is measured on the chip by ``bench/run.py``.
 
   PYTHONPATH=src python examples/serve_batched.py
 """

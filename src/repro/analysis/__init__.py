@@ -67,9 +67,7 @@ machine-checkable invariants on the lowered artifact:
   module (``tf.aliasing_output``); params are the one exempt argument;
 - **no shape growth** — no intermediate tensor larger than the largest
   signature (input/output) tensor, plus the paged-specific ban on the
-  full gathered ``[slots, max_blocks*block_size, ...]`` K/V transient
-  (this generalizes and replaces the bespoke lowered-HLO assert
-  ``bench_serve.py`` carried since the chunked-prefill PR);
+  full gathered ``[slots, max_blocks*block_size, ...]`` K/V transient;
 - **stable jit cache keys** — serving a second, different trace through
   an already-warm scheduler adds ZERO new executables: the recompile
   counter equals the number of distinct executables the config needs;

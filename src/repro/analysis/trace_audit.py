@@ -4,9 +4,8 @@ The AST lint (Layer 1) reads source; this module reads what XLA will
 actually run. It lowers the serving executables — ``engine.prefill``,
 ``engine.decode_step``, ``engine.mixed_step``, plus the speculative
 pair ``layerskip.draft_window`` / ``engine.verify_step``, contiguous
-and paged — for the same smoke configuration
-``benchmarks/bench_serve.py`` serves, and asserts four invariants on
-the lowered StableHLO:
+and paged — for the smoke serving configuration below, and asserts four
+invariants on the lowered StableHLO:
 
 - **donation coverage** (:func:`audit_donation`): every non-exempt
   argument leaf at least ``min_bytes`` big is donated AND the module
@@ -30,9 +29,7 @@ the lowered StableHLO:
 
 All audit functions return a list of human-readable failure strings
 (empty = clean); :func:`run_trace_audit` runs the whole matrix and is
-what ``python -m repro.analysis`` and CI call. ``benchmarks/
-bench_serve.py`` calls :func:`paged_growth_patterns` +
-:func:`audit_no_growth` instead of its former bespoke HLO assert.
+what ``python -m repro.analysis`` and CI call.
 """
 from __future__ import annotations
 
@@ -41,8 +38,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-# Geometry mirror of benchmarks/bench_serve.py's smoke serving config —
-# the audit must lower the executables the benchmark actually replays.
+# The smoke serving geometry the audit lowers (llama3.2-1b smoke widths,
+# a 4-slot pool of 16-token blocks).
 ARCH = "llama3.2-1b"
 SLOTS = 4
 PAD_TO = 16
@@ -144,13 +141,12 @@ def audit_donation(lowered, *, exempt_args: Sequence[int] = (0,),
 
 
 def donation_summary(lowered) -> Dict[str, int]:
-    """Leaf-count summary for the checked-in perf snapshot
-    (benchmarks/BENCH_serve.json): how many argument leaves the
-    executable takes, how many are donated, and how many donations the
+    """Leaf-count summary of one lowered executable: how many argument
+    leaves it takes, how many are donated, and how many donations the
     compiled text actually aliases to outputs. A drop in
-    ``donated_leaves``/``aliased_outputs`` between snapshots means a
-    donation silently regressed even if sizes stayed under the
-    audit_donation threshold."""
+    ``donated_leaves``/``aliased_outputs`` means a donation silently
+    regressed even if sizes stayed under the audit_donation
+    threshold."""
     import jax.tree_util as jtu
 
     leaves = [l for t in _arg_trees(lowered) for l in jtu.tree_leaves(t)]
@@ -455,7 +451,7 @@ def lower_serving_tp(model, params, *, tp: int = 2, slots: int = SLOTS,
 
 def run_trace_audit(verbose: bool = False,
                     include_recompiles: bool = True) -> List[str]:
-    """Run the whole audit matrix on the bench_serve smoke config.
+    """Run the whole audit matrix on the smoke serving config.
     Returns failure strings; empty means the serving hot path holds all
     four invariants."""
     import jax

@@ -1,7 +1,7 @@
 """Config registry: ``--arch <id>`` resolution for launchers and tests."""
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, Tuple
 
 from repro.configs.base import (  # noqa: F401
     EncDecConfig,
@@ -12,7 +12,6 @@ from repro.configs.base import (  # noqa: F401
     SSMConfig,
     VLMConfig,
 )
-from repro.configs.shapes import INPUT_SHAPES, InputShape  # noqa: F401
 
 from repro.configs import (
     chameleon_34b,
@@ -61,43 +60,3 @@ def get_config(arch: str) -> ModelConfig:
 
 def get_smoke_config(arch: str) -> ModelConfig:
     return SMOKE_CONFIGS[arch]
-
-
-def get_shape(name: str) -> InputShape:
-    return INPUT_SHAPES[name]
-
-
-def config_for_shape(arch: str, shape: InputShape) -> ModelConfig:
-    """Resolve the config actually lowered for (arch, shape).
-
-    llama3.2-1b swaps in its sliding-window variant for long_500k (the
-    beyond-paper dense long-context path); other archs are returned as-is
-    (callers must consult :func:`shape_supported` first).
-    """
-    cfg = get_config(arch)
-    if shape.requires_subquadratic and cfg.family == "dense":
-        if arch == "llama3.2-1b":
-            from repro.configs.llama3_2_1b import CONFIG_SWA
-
-            return CONFIG_SWA
-    return cfg
-
-
-def shape_supported(arch: str, shape: InputShape) -> Tuple[bool, str]:
-    """(supported, reason) for the 40-pair table, per DESIGN.md §4."""
-    cfg = get_config(arch)
-    if shape.name == "long_500k":
-        if cfg.family in ("ssm", "hybrid"):
-            return True, "sub-quadratic native"
-        if arch == "llama3.2-1b":
-            return True, "sliding-window variant (beyond-paper)"
-        if cfg.family == "encdec":
-            return False, "enc-dec: decoder context bounded by encoder output"
-        return False, "pure full-attention arch (skip noted in DESIGN.md)"
-    if cfg.family == "encdec" and shape.kind == "decode" and shape.seq_len > 32_768:
-        return False, "enc-dec decoder window < seq_len"
-    return True, ""
-
-
-def all_pairs() -> List[Tuple[str, InputShape]]:
-    return [(a, s) for a in ASSIGNED_ARCHS for s in INPUT_SHAPES.values()]

@@ -1,10 +1,6 @@
 """Llama-3.2-1B [hf:meta-llama/Llama-3.2-1B] — small llama3, tied embeddings.
 
 16L d_model=2048 32H (GQA kv=8) d_ff=8192 vocab=128256.
-
-Also exposes a sliding-window variant (``CONFIG_SWA``) used for the
-long_500k decode shape — a beyond-paper extension enabling dense archs to
-serve 524k contexts with a ring-buffer KV cache.
 """
 from repro.configs.base import ModelConfig
 
@@ -20,8 +16,6 @@ CONFIG = ModelConfig(
     rope_theta=500_000.0,
     tie_embeddings=True,
 )
-
-CONFIG_SWA = CONFIG.replace(name="llama3.2-1b-swa", sliding_window=8192)
 
 SMOKE = CONFIG.replace(
     name="llama3.2-1b-smoke",

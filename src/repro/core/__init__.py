@@ -1,9 +1,7 @@
 """The paper's primary contribution as a composable feature set:
 static-KV-cache serving engine, decoding strategies (incl. beam reorder),
-LayerSkip self-speculative decoding, AutoQuant, and the operator-class
-characterization used by the benchmarks."""
+LayerSkip self-speculative decoding and AutoQuant."""
 from repro.core import (  # noqa: F401
-    characterization,
     engine,
     kv_cache,
     layerskip,

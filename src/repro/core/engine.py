@@ -364,7 +364,7 @@ def generate_scanned(
     extra_inputs: Optional[Dict[str, jnp.ndarray]] = None,
 ) -> jnp.ndarray:
     """Whole-generation-as-one-program variant: prefill + lax.scan decode.
-    This is the fully static pipeline the dry-run lowers for decode shapes
+    The fully static pipeline: no host round trip between tokens
     (profiles' host-side control flow excludes them from this path)."""
     b, tp = prompt_tokens.shape
     prompt_lengths = jnp.full((b,), tp, jnp.int32)

@@ -1,5 +1,5 @@
 """KV-cache management: the paper's §4.1.2 static-cache discipline plus the
-Obs #4 beam-search reorder lever, and the dynamic-cache anti-baseline.
+Obs #4 beam-search reorder lever.
 
 The cache layout itself lives with the models (models/attention.py etc.);
 this module owns the cross-cutting operations:
@@ -10,14 +10,11 @@ this module owns the cross-cutting operations:
                          paper's ``torch.Tensor.copy_`` fix (no fresh
                          allocation + fusable into the step program).
 - ``reorder_realloc``  — the paper's *unoptimized* ``index_select``
-                         behavior: forces a fresh buffer each step (for the
-                         bench_compile A/B).
+                         behavior: forces a fresh buffer each step
+                         (``engine.generate_beam(donate_reorder=False)``).
 - ``rewind``           — speculative-decoding rollback: shrink ``lengths``
                          (stale entries beyond are overwritten/masked).
 - ``cache_bytes``      — memory accounting per Fig 1.
-- ``DynamicCache``     — concat-grown cache that changes shape every step,
-                         forcing an XLA recompile per token: the JAX
-                         equivalent of the paper's eager-PyTorch baseline.
 
 Block-table addressing vs the §4.1.2 contiguous discipline
 ----------------------------------------------------------
@@ -180,7 +177,7 @@ make that work, by construction:
 - the garbage-sink convention (block 0) and validity masks are
   positional, so they shard along for free.
 
-The invariant the TP gates enforce (bench_serve --tp): sharded serving
+The invariant ``tests/test_tp.py`` holds it to: sharded serving
 is TOKEN-identical to single-device serving at any temperature — the
 row-sharded psum moves logits by at most an ulp, which argmax and top-p
 sampling survive.
@@ -188,7 +185,7 @@ sampling survive.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -333,28 +330,3 @@ def cache_token_bytes(cache: Any) -> float:
     seq_leaves = [x for x in leaves if x.ndim >= 3]
     s = max((x.shape[1] for x in seq_leaves), default=1)
     return cache_bytes(cache) / (b * s)
-
-
-class DynamicCache:
-    """Concat-grown KV cache (the anti-pattern the paper's static cache
-    replaces). Shapes change every decode step => jax.jit recompiles every
-    step => the "GPU idle / launch overhead" pathology of Obs #2, expressed
-    in XLA terms. Used only by benchmarks/bench_compile.py."""
-
-    def __init__(self):
-        self.layers: Dict[int, Dict[str, jnp.ndarray]] = {}
-
-    def append(self, layer: int, k: jnp.ndarray, v: jnp.ndarray):
-        if layer not in self.layers:
-            self.layers[layer] = {"k": k, "v": v}
-        else:
-            c = self.layers[layer]
-            c["k"] = jnp.concatenate([c["k"], k], axis=1)
-            c["v"] = jnp.concatenate([c["v"], v], axis=1)
-        return self.layers[layer]["k"], self.layers[layer]["v"]
-
-    @property
-    def seq_len(self) -> int:
-        if not self.layers:
-            return 0
-        return next(iter(self.layers.values()))["k"].shape[1]

@@ -54,7 +54,7 @@ radix tree *across requests*:
 
 The trie is pure host state (dicts over byte-span keys); it allocates no
 device memory, so enabling the cache changes reserved KV bytes by ZERO —
-reuse, not growth (`bench_serve --prefix-cache` gates this).
+reuse, not growth (``tests/test_prefix_cache.py`` holds it to this).
 """
 from __future__ import annotations
 

@@ -22,17 +22,15 @@ array, so jit specializes on it statically).
 """
 from __future__ import annotations
 
-import dataclasses
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
 from repro.kernels import ops
 
-# v5e roofline constants (see launch/roofline.py)
+# v5e peaks (bench/peaks.py holds the benchmark's copy)
 PEAK_FLOPS_BF16 = 197e12
 HBM_BW = 819e9
 RIDGE_TOKENS = PEAK_FLOPS_BF16 / HBM_BW / 2  # ≈120 rows: bf16 GEMM ridge

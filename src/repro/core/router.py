@@ -45,8 +45,9 @@ on a single-device host they time-share it). Throughput accounting uses
 each replica's ``busy_s`` (host wall time of its admissions and steps):
 ``total tokens / max-over-replicas busy_s`` is the fleet's aggregate
 service rate — the wall a real one-device-per-replica deployment would
-take — and is what ``bench_serve --replicas`` gates near-linear scaling
-on, alongside the deterministic per-replica step-count balance.
+take. ``tests/test_router.py`` holds the deterministic half of that
+claim: on a backlog, the busiest replica runs a fraction of the single
+pool's steps.
 """
 from __future__ import annotations
 

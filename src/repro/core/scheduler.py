@@ -19,9 +19,8 @@ static-shape discipline:
 
 ``policy="fixed"`` degrades the same machinery to the paper's baseline:
 admission only happens when the pool is completely drained (run-to-
-completion batches), which is the A/B lever ``benchmarks/bench_serve.py``
-measures. Both policies share every compiled program, so the comparison
-isolates scheduling.
+completion batches). Both policies share every compiled program, so the
+comparison isolates scheduling.
 
 ``paged=True`` swaps the contiguous SlotPool for the BlockPool (paged KV,
 vLLM-style): admission is additionally gated on free *blocks* (the

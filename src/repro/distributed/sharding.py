@@ -1,5 +1,5 @@
 """Sharding rules: param / optimizer / activation / cache PartitionSpecs
-for the production mesh (pod, data, model).
+for a (pod, data, model) mesh.
 
 Baseline scheme (megatron-style TP + expert parallel + DP):
 
@@ -11,9 +11,7 @@ Baseline scheme (megatron-style TP + expert parallel + DP):
 
 Rules are applied by *path pattern* over the param tree, with divisibility
 guards: a dim is only sharded if it divides evenly by the mesh axis size
-(GQA KV projections with kv_heads < model_size stay replicated — the
-baseline cost that the sequence-parallel decode path removes; see
-EXPERIMENTS.md §Perf).
+(GQA KV projections with kv_heads < model_size stay replicated).
 
 Scanned-layer stacks ([L, ...] leaves) get the same spec shifted right by
 one (the layer axis is never sharded).
@@ -45,7 +43,6 @@ import re
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -168,8 +165,7 @@ def opt_state_specs(
 def cache_specs(cfg: ModelConfig, cache_like: Any, mesh: Mesh, batch: int) -> Any:
     """KV-cache specs: batch dim over (pod, data); everything else
     replicated in the baseline (kv_heads rarely divide the model axis).
-    The sequence-parallel decode variant re-shards the S axis over 'model'
-    — see launch/dryrun.py seq_shard option."""
+    :func:`cache_specs_seqsharded` re-shards the S axis over 'model'."""
     daxes = data_axes(mesh)
     total = 1
     for a in daxes:
@@ -195,7 +191,7 @@ def cache_specs_seqsharded(
 ) -> Any:
     """Beyond-paper variant: shard the cache SEQUENCE axis over 'model'
     (flash-decode sequence parallelism). Applies to [.., S, H, D] KV leaves
-    with S divisible; the LSE-combine happens inside decode attention."""
+    with S divisible; GSPMD partitions the attention reduction over it."""
     base = cache_specs(cfg, cache_like, mesh, batch)
     msize = _axis_size(mesh, "model")
 

@@ -4,9 +4,9 @@ Each op has up to three implementations:
 
 - ``ref``    — the naive oracle in :mod:`repro.kernels.ref` (materializes).
 - ``xla``    — a memory-efficient pure-XLA implementation (chunked online
-               softmax / chunked SSD). This is what lowers in the CPU
-               container and in the multi-pod dry-run; it is the paper's
-               "SDPA" lever expressed in XLA.
+               softmax / chunked SSD). This is what runs off the TPU
+               (the CPU tests); it is the paper's "SDPA" lever expressed
+               in XLA.
 - ``pallas`` — the TPU Pallas kernel (VMEM-tiled). Validated on CPU with
                ``interpret=True``; selected on real TPU backends.
 
@@ -16,7 +16,6 @@ xla path (§Perf lever: skips fully-masked KV blocks instead of masking).
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -69,14 +68,6 @@ def _on_own_heads(kernel, q_head_axis: int, q, k, v, *rest):
 
 
 NEG_INF = -1e30  # finite sentinel: keeps online softmax NaN-free
-
-#: XLA flash-attention inner loop layout: "stacked" pre-transposes KV into
-#: [n_blocks, ...] scan inputs (baseline; XLA materializes a transposed
-#: copy AND hoists the f32 convert of the whole cache out of the loop —
-#: §Perf measured ~5-10 full-cache passes per decode layer on MLA), or
-#: "sliced" which dynamic-slices the original buffer per block inside the
-#: loop body (no copy, convert stays per-block). Launchers flip this.
-XLA_FLASH_LAYOUT = "stacked"
 
 
 # --------------------------------------------------------------------------
@@ -165,10 +156,6 @@ def _flash_xla(q, k, v, qpos, kpos, causal, window, k_valid, scale, block_k):
     # Masked pad rows contribute exact zeros to the online softmax, so
     # clamping is bit-identical.
     block_k = min(block_k, max(k.shape[1], 1))
-    if XLA_FLASH_LAYOUT == "sliced":
-        return _flash_xla_sliced(
-            q, k, v, qpos, kpos, causal, window, k_valid, scale, block_k
-        )
     b, tq, hq, d = q.shape
     s, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
@@ -226,61 +213,6 @@ def _flash_xla(q, k, v, qpos, kpos, causal, window, k_valid, scale, block_k):
         else (k_blocks, v_blocks, kpos_blocks, kval_blocks)
     )
     (m, l, acc), _ = jax.lax.scan(step, (m0, l0, acc0), xs)
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(b, tq, hq, dv).astype(q.dtype)
-
-
-def _flash_xla_sliced(q, k, v, qpos, kpos, causal, window, k_valid, scale,
-                      block_k):
-    """Index-scanned flash attention: each step dynamic-slices the ORIGINAL
-    [B, S, H, D] buffers (no [n_blocks,...] transposed copy, f32 converts
-    stay per-block inside the loop). Same math as the stacked layout."""
-    b, tq, hq, d = q.shape
-    s, hkv = k.shape[1], k.shape[2]
-    g = hq // hkv
-    dv = v.shape[-1]
-
-    pad = (-s) % block_k
-    if k_valid is None:
-        k_valid = jnp.broadcast_to(jnp.arange(s)[None, :] < s, (b, s))
-    else:
-        k_valid = jnp.broadcast_to(k_valid, (b, s))
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kpos = jnp.pad(kpos, ((0, 0), (0, pad)))
-        k_valid = jnp.pad(k_valid, ((0, 0), (0, pad)))
-    n_blk = (s + pad) // block_k
-
-    qf = (q.astype(jnp.float32) * scale).reshape(b, tq, hkv, g, d)
-    m0 = jnp.full((b, tq, hkv, g), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, tq, hkv, g), jnp.float32)
-    acc0 = jnp.zeros((b, tq, hkv, g, dv), jnp.float32)
-
-    def step(carry, i):
-        m, l, acc = carry
-        lo = i * block_k
-        kb = jax.lax.dynamic_slice_in_dim(k, lo, block_k, 1)
-        vb = jax.lax.dynamic_slice_in_dim(v, lo, block_k, 1)
-        kpb = jax.lax.dynamic_slice_in_dim(kpos, lo, block_k, 1)
-        kvb = jax.lax.dynamic_slice_in_dim(k_valid, lo, block_k, 1)
-        scores = jnp.einsum(
-            "bthgd,bshd->bthgs", qf, kb.astype(jnp.float32)
-        )
-        bias = _mask_bias(qpos, kpb, causal, window, kvb)
-        scores = scores + bias[:, :, None, None, :]
-        new_m = jnp.maximum(m, scores.max(axis=-1))
-        alpha = jnp.exp(m - new_m)
-        p = jnp.exp(scores - new_m[..., None])
-        new_l = l * alpha + p.sum(axis=-1)
-        new_acc = acc * alpha[..., None] + jnp.einsum(
-            "bthgs,bshd->bthgd", p, vb.astype(jnp.float32)
-        )
-        return (new_m, new_l, new_acc), None
-
-    (m, l, acc), _ = jax.lax.scan(
-        step, (m0, l0, acc0), jnp.arange(n_blk, dtype=jnp.int32)
-    )
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.reshape(b, tq, hq, dv).astype(q.dtype)
 
@@ -365,15 +297,15 @@ def decode_attention(
 
 def decode_attention_partial(
     q: jnp.ndarray,  # [B, Hq, D]
-    k: jnp.ndarray,  # [B, S_shard, Hkv, D] — a shard of the cache
+    k: jnp.ndarray,  # [B, S_tile, Hkv, D] — a tile of the keys
     v: jnp.ndarray,
-    k_valid: jnp.ndarray,  # [B, S_shard]
+    k_valid: jnp.ndarray,  # [B, S_tile]
     *,
     scale: Optional[float] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Flash-decode partial: returns (acc [B,Hq,Dv], m [B,Hq], l [B,Hq])
-    for LSE-combination across cache shards (the sequence-parallel decode
-    path; combine with :func:`combine_partial_attention`)."""
+    for LSE-combination across key tiles (the paged decode walk folds
+    them with :func:`merge_partial_attention`)."""
     b, s, hkv, d = k.shape
     hq = q.shape[1]
     g = hq // hkv

@@ -1,2 +1,3 @@
-# NOTE: do not import dryrun here — it sets XLA_FLAGS at import time.
+"""Entry points: the serving CLI (``serve``), the training loop
+(``train``), device meshes and the persistent compile cache."""
 from repro.launch import mesh  # noqa: F401

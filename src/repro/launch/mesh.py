@@ -1,31 +1,11 @@
-"""Production mesh definitions (TPU v5e target).
-
-Single pod: 16×16 = 256 chips, axes (data, model).
-Multi-pod: 2×16×16 = 512 chips, axes (pod, data, model); the pod axis is
-pure data parallel over DCI.
+"""Device meshes for the launchers.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state (the dry-run sets XLA_FLAGS before first jax init).
+state.
 """
 from __future__ import annotations
 
 import jax
-
-HW = {
-    "name": "tpu-v5e",
-    "peak_flops_bf16": 197e12,  # per chip
-    "peak_flops_int8": 394e12,
-    "hbm_bw": 819e9,  # bytes/s per chip
-    "hbm_bytes": 16 * 1024 ** 3,
-    "ici_bw": 50e9,  # bytes/s per link
-    "chips_per_pod": 256,
-}
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int | None = None):

@@ -1,9 +1,8 @@
 """Training launcher: ``--arch <id>`` + mesh flags.
 
-On real hardware this builds the production mesh and jits train_step with
-the sharding rules from distributed/sharding.py; in this CPU container it
-defaults to the local device set (use examples/train_small.py for a real
-local run; use launch/dryrun.py for the production-mesh compile proof).
+Builds a (data, model) mesh over the local devices
+(``mesh.make_debug_mesh``) and jits train_step with the sharding rules from
+distributed/sharding.py. examples/train_small.py is the smaller local run.
 
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b \
       --steps 100 --batch 4 --seq 128 [--smoke] [--ckpt out.npz]

@@ -33,19 +33,11 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis.hotpath import hot_path
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.models import layers as L
-
-#: When set (by the launcher/dry-run --decode-sp), the decode path runs
-#: sequence-parallel under shard_map: the KV cache shards its SEQUENCE
-#: axis over 'model', each device computes flash-decode partials on its
-#: shard, and an LSE-combine merges them — instead of GSPMD all-gathering
-#: the whole cache (the §Perf-measured 270GB/step pathology on 405B).
-SP_MESH: Optional[Mesh] = None
 
 
 # --------------------------------------------------------------------------
@@ -367,62 +359,6 @@ def paged_mixed_attention(
     return jnp.concatenate([head, tail.astype(head.dtype)])
 
 
-def _sp_decode(cache, k_new, v_new, q, lengths):
-    """Sequence-parallel flash decode under shard_map.
-
-    Cache K/V [B, S, Hkv, D] shard the S axis over 'model'; each device:
-    (1) writes the new token's K/V iff it owns slot ``lengths``,
-    (2) computes flash-decode partials (acc, m, l) over its local shard,
-    (3) all-gathers the tiny per-head partials and LSE-combines.
-    Collective cost per layer: 3 × [B, Hq, (D+2)] floats instead of the
-    GSPMD baseline's full-cache all-gather.
-    """
-    mesh = SP_MESH
-    msize = mesh.shape["model"]
-    daxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    bspec = daxes if daxes else None
-    kv_spec = P(bspec, "model", None, None)
-    vec_spec = P(bspec, None, None)
-
-    def local(ck, cv, kn, vn, qv, ln):
-        s_loc = ck.shape[1]
-        shard = jax.lax.axis_index("model")
-        local_idx = ln - shard * s_loc
-        in_range = (local_idx >= 0) & (local_idx < s_loc)
-        wi = jnp.clip(local_idx, 0, s_loc - 1)
-        # conditional single-row write: non-owners rewrite the row they
-        # already hold (a full-buffer where() would triple cache traffic —
-        # §Perf round 2 measured it at ~3x the live bytes)
-        def row_at(buf, i):
-            return jax.vmap(
-                lambda b, j: jax.lax.dynamic_slice(
-                    b, (j,) + (0,) * (b.ndim - 1), (1,) + b.shape[1:]
-                )[0]
-            )(buf, i)
-
-        sel = in_range[:, None, None]
-        ck2 = write_decode(ck, jnp.where(sel, kn, row_at(ck, wi)), wi)
-        cv2 = write_decode(cv, jnp.where(sel, vn, row_at(cv, wi)), wi)
-
-        base = shard * s_loc
-        n_valid = ln + 1
-        k_valid = (base + jnp.arange(s_loc))[None, :] < n_valid[:, None]
-        acc, m, l = ops.decode_attention_partial(qv, ck2, cv2, k_valid)
-        accs = jax.lax.all_gather(acc, "model")  # [msize, B, Hq, D]
-        ms = jax.lax.all_gather(m, "model")
-        ls = jax.lax.all_gather(l, "model")
-        out = ops.combine_partial_attention(accs, ms, ls)
-        return out.astype(qv.dtype), ck2, cv2
-
-    out, ck2, cv2 = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(kv_spec, kv_spec, vec_spec, vec_spec, vec_spec, P(bspec)),
-        out_specs=(vec_spec, kv_spec, kv_spec),
-        check_vma=False,
-    )(cache["k"], cache["v"], k_new, v_new, q, lengths)
-    return out, {"k": ck2, "v": cv2}
-
-
 # --------------------------------------------------------------------------
 # standard GQA attention
 # --------------------------------------------------------------------------
@@ -496,10 +432,6 @@ def attention(
     elif mode in ("decode", "mixed", "verify") and "bt" in cache:
         if window is not None:
             raise NotImplementedError("paged cache unsupported on ring/window")
-        if SP_MESH is not None:
-            raise NotImplementedError(
-                "paged decode unsupported under sequence-parallel shard_map"
-            )
         bt = cache["bt"]  # [B, max_blocks] int32
         bs = cache["k"].shape[1]
         if mode == "decode":
@@ -550,10 +482,6 @@ def attention(
         # causality via the query positions, per-slot width via t_new.
         if window is not None:
             raise NotImplementedError("verify unsupported on ring/window caches")
-        if SP_MESH is not None:
-            raise NotImplementedError(
-                "verify unsupported under sequence-parallel shard_map"
-            )
         s = cache["k"].shape[1]
         new_cache = {
             "k": write_window(cache["k"], k, lengths, t_new),
@@ -566,20 +494,16 @@ def attention(
             k_positions=kpos, causal=True, k_valid=k_valid, impl=impl,
         )
     elif mode == "decode":
-        if SP_MESH is not None and window is None:
-            out, new_cache = _sp_decode(cache, k[:, 0], v[:, 0], q[:, 0], lengths)
-            out = out[:, None]
-        else:
-            s = cache["k"].shape[1]
-            idx = lengths % s
-            new_cache = {
-                "k": write_decode(cache["k"], k[:, 0], idx),
-                "v": write_decode(cache["v"], v[:, 0], idx),
-            }
-            n_valid = valid_counts(lengths + 1, s)
-            out = ops.decode_attention(
-                q[:, 0], new_cache["k"], new_cache["v"], n_valid, impl=impl
-            )[:, None]
+        s = cache["k"].shape[1]
+        idx = lengths % s
+        new_cache = {
+            "k": write_decode(cache["k"], k[:, 0], idx),
+            "v": write_decode(cache["v"], v[:, 0], idx),
+        }
+        n_valid = valid_counts(lengths + 1, s)
+        out = ops.decode_attention(
+            q[:, 0], new_cache["k"], new_cache["v"], n_valid, impl=impl
+        )[:, None]
     elif mode == "extend":
         if "bt" in cache:
             raise NotImplementedError("extend unsupported on paged caches")

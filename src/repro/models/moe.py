@@ -20,7 +20,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 
-#: When set (by the launcher/dry-run), MoE layers route through the
+#: When set (only tests set it), MoE layers route through the
 #: shard_map expert-parallel path with explicit all-to-all dispatch
 #: (moe_ffn_ep) instead of the GSPMD dense-dispatch baseline. The baseline
 #: lets XLA all-gather every token to every expert shard AND replicates

@@ -3,7 +3,7 @@ in a uniform `Model` handle used by the engine, launcher, and tests."""
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +49,7 @@ class Model:
         )
 
     def abstract_params(self):
-        """ShapeDtypeStruct tree of params — no allocation (dry-run path)."""
+        """ShapeDtypeStruct tree of params — no allocation."""
         return jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
 
     def abstract_cache(self, batch: int, max_len: int):

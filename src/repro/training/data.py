@@ -2,15 +2,15 @@
 
 Table 2 / §3.1: each task has a characteristic input/output sequence-length
 distribution that drives its latency profile (Obs #1). The generators here
-sample those distributions so benchmarks/bench_seqlen.py can reproduce the
-paper's Fig 3 latency spread, and the training loop has an infinite token
-stream (deterministic per seed, sharded by data-parallel rank).
+sample those distributions for the serving CLI's request traces
+(``launch/serve.py``), and the training loop has an infinite token stream
+(deterministic per seed, sharded by data-parallel rank).
 """
 from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Dict, Iterator, Optional, Tuple
+from typing import Dict, Iterator, Tuple
 
 import numpy as np
 

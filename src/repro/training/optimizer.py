@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +20,7 @@ class OptimizerConfig:
     warmup_steps: int = 100
     total_steps: int = 10_000
     min_lr_frac: float = 0.1
-    # moment dtype: f32 default; bf16 halves optimizer HBM (a §Perf lever
-    # for the 405B train dry-run)
+    # moment dtype: f32 default; bf16 halves optimizer HBM
     moment_dtype: str = "float32"
 
 

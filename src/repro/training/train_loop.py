@@ -1,8 +1,7 @@
-"""Training loop: loss, train_step (the function the dry-run lowers for
-train_4k), and a host-side loop with checkpointing and metrics."""
+"""Training loop: loss, train_step, and a host-side loop with
+checkpointing and metrics."""
 from __future__ import annotations
 
-import functools
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterator, Optional, Tuple

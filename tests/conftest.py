@@ -1,7 +1,7 @@
 import os
 
-# Tests run single-device on CPU: the dry-run (and ONLY the dry-run) forces
-# 512 placeholder devices, in its own subprocess.
+# Tests run single-device on CPU; a test that needs several devices forces
+# host devices in its own subprocess.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 try:

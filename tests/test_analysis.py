@@ -330,3 +330,30 @@ def test_donation_summary_counts(big):
 def test_paged_growth_patterns_shapes():
     assert trace_audit.paged_growth_patterns(4, 6, 16) == [
         "tensor<4x96x", "tensor<4x6x16x"]
+
+
+def test_paged_decode_step_materialises_no_full_gather():
+    """The paged decode step walks the block table in tiles: where a
+    slot's table spans several tiles (66 blocks of 16, 1,056 keys, against
+    tiles of at most ``TILE_KEYS`` = 512), no full gathered ``[slots,
+    max_blocks*block_size, ...]`` K/V transient appears and no
+    intermediate exceeds the static envelope. (Below one tile's keys, the
+    one tile IS the whole table.)"""
+    from repro.configs import SMOKE_CONFIGS
+    from repro.models import attention, get_model
+
+    cfg = SMOKE_CONFIGS[trace_audit.ARCH].replace(dtype="float32")
+    model = get_model(cfg)
+    slots, block_size, max_new_cap = 4, 16, 1024
+    lowered = trace_audit.lower_serving(
+        model, model.init(jax.random.PRNGKey(0)), paged=True, slots=slots,
+        max_new_cap=max_new_cap, block_size=block_size,
+        num_blocks=4 * 66 + 1)
+    pool = lowered.pop("_pool")
+    assert pool.max_blocks == 66
+    assert pool.max_blocks * block_size > attention.TILE_KEYS
+    forbidden = trace_audit.paged_growth_patterns(
+        slots, pool.max_blocks, block_size)
+    assert trace_audit.audit_no_growth(
+        lowered["decode_step"], forbidden=forbidden,
+        label="paged/decode_step") == []

@@ -1,7 +1,6 @@
 """Multi-device distributed-path tests. Each test runs in a subprocess
 with XLA_FLAGS=--xla_force_host_platform_device_count=8 so the main test
-process keeps a single device (per DESIGN: only the dry-run and these
-tests fake device counts)."""
+process keeps a single device."""
 import os
 import subprocess
 import sys
@@ -54,38 +53,6 @@ def test_moe_expert_parallel_equals_dense_dispatch():
         """
     )
     assert "EP_OK" in out
-
-
-@pytest.mark.slow
-def test_sequence_parallel_decode_equals_reference():
-    out = run_in_subprocess(
-        """
-        from repro.models import attention as A
-        from repro.models.attention import write_decode
-        from repro.kernels import ref as kref
-        key = jax.random.PRNGKey(0)
-        B, S, Hq, Hkv, D = 4, 32, 8, 2, 16
-        ks = jax.random.split(key, 5)
-        ck = jax.random.normal(ks[0], (B, S, Hkv, D))
-        cv = jax.random.normal(ks[1], (B, S, Hkv, D))
-        kn = jax.random.normal(ks[2], (B, Hkv, D))
-        vn = jax.random.normal(ks[3], (B, Hkv, D))
-        q = jax.random.normal(ks[4], (B, Hq, D))
-        lengths = jnp.array([5, 12, 31, 20])
-        want = kref.decode_attention_ref(
-            q, write_decode(ck, kn, lengths), write_decode(cv, vn, lengths),
-            lengths + 1)
-        A.SP_MESH = mesh
-        with mesh:
-            got, newc = jax.jit(A._sp_decode)({"k": ck, "v": cv}, kn, vn, q, lengths)
-        err = float(np.abs(np.asarray(got) - np.asarray(want)).max())
-        cerr = float(np.abs(np.asarray(newc["k"]) -
-                            np.asarray(write_decode(ck, kn, lengths))).max())
-        assert err < 1e-5 and cerr == 0.0, (err, cerr)
-        print("SP_OK", err)
-        """
-    )
-    assert "SP_OK" in out
 
 
 @pytest.mark.slow

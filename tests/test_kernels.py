@@ -88,7 +88,7 @@ def test_decode_attention_sweep(b, s, hq, hkv, d, dtype, impl):
 
 
 def test_decode_partial_combine_matches_full():
-    """Flash-decode LSE-combine (the sequence-parallel decode primitive)."""
+    """Flash-decode LSE-combine of partials over splits of the keys."""
     ks = jax.random.split(KEY, 4)
     b, s, hq, hkv, d = 2, 64, 8, 2, 32
     q = rand(ks[0], (b, hq, d))

@@ -2,12 +2,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.configs import CONFIGS, SMOKE_CONFIGS, get_shape
+from repro.configs import CONFIGS, SMOKE_CONFIGS
+from repro.core.quantization import quantize_params
 from repro.distributed import sharding as sh
-from repro.launch import specs as sp
 from repro.models import get_model
 
 
@@ -18,27 +17,32 @@ class FakeMesh:
 
 
 MESH16 = FakeMesh({"data": 16, "model": 16})
+# 405B with its 126 layers scanned into one stack: shapes only, never built
+CFG405 = CONFIGS["llama3-405b"].replace(scan_layers=True)
+
+
+def _param_structs(quant=None):
+    """ShapeDtypeStruct params of CFG405, AutoQuant'd when ``quant``."""
+    params = get_model(CFG405).abstract_params()
+    if quant:
+        params = jax.eval_shape(lambda p: quantize_params(p, quant), params)
+    return params
 
 
 def test_quantized_step_spec_struct():
     """--quant wo produces int8 weight stacks (3-D scanned aware)."""
-    spec = sp.make_step_spec("llama3-405b", get_shape("decode_32k"), quant="wo")
-    params = spec.arg_structs[0]
+    params = _param_structs("wo")
     assert params["scanned"]["ffn"]["w1"]["w_q_wo"].dtype == jnp.int8
     assert params["scanned"]["ffn"]["w1"]["w_q_wo"].shape[0] == 126  # full stack
     total = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(params))
     bf16_total = sum(
-        l.size * l.dtype.itemsize
-        for l in jax.tree.leaves(sp.make_step_spec(
-            "llama3-405b", get_shape("decode_32k")).arg_structs[0])
+        l.size * l.dtype.itemsize for l in jax.tree.leaves(_param_structs())
     )
     assert total < 0.55 * bf16_total  # ~halved weight bytes
 
 
 def test_quantized_params_still_sharded():
-    cfg = CONFIGS["llama3-405b"].replace(scan_layers=True)
-    spec = sp.make_step_spec("llama3-405b", get_shape("decode_32k"), quant="wo")
-    specs = sh.param_specs(cfg, spec.arg_structs[0], MESH16)
+    specs = sh.param_specs(CFG405, _param_structs("wo"), MESH16)
     assert specs["scanned"]["ffn"]["w1"]["w_q_wo"] == P(None, None, "model")
 
 

@@ -180,6 +180,67 @@ def test_warm_hits_are_token_identical_to_cold(llama, temperature):
     assert warm.pool.reserved_bytes == reserved == cold.pool.reserved_bytes
 
 
+def _system_prompt_trace(vocab, temperature):
+    """20 requests over 2 system prompts of 4 full blocks each, reused
+    Zipf-style, each with a fresh 1..8-token suffix, all at t=0."""
+    from repro.launch import serve
+
+    return serve.shared_prefix_trace(
+        20, n_prefixes=2, prefix_len=4 * BS, pad_to=24, max_new_cap=8,
+        vocab_size=vocab, arrival_rate=0.0, seed=0, temperature=temperature,
+        top_p=0.9 if temperature else 1.0,
+    )
+
+
+def _system_prompt_sched(model, params, prefix_cache):
+    return Scheduler(
+        model, params, slots=4, pad_to=24, max_new_cap=8, paged=True,
+        block_size=BS, num_blocks=48, chunked=True, prefill_budget=8,
+        prefix_cache=prefix_cache,
+    )
+
+
+def test_system_prompt_traffic_serves_most_prompt_tokens_from_cache(llama):
+    """On shared-system-prompt traffic served once, at least half of all
+    prompt tokens come out of cached blocks instead of prefill, with the
+    cold scheduler's tokens and reserved bytes."""
+    model, params = llama
+    vocab = model.config.vocab_size
+    cold = _system_prompt_sched(model, params, prefix_cache=False)
+    ref = _tokens(cold.run(_system_prompt_trace(vocab, 0.0)))
+    warm = _system_prompt_sched(model, params, prefix_cache=True)
+    reqs = _system_prompt_trace(vocab, 0.0)
+    total = sum(len(r.prompt) for r in reqs)
+    got = _tokens(warm.run(reqs))
+    assert got == ref and len(got) == 20
+    assert warm.n_prefix_tokens_skipped >= 0.5 * total, (
+        warm.n_prefix_tokens_skipped, total)
+    assert warm.pool.reserved_bytes == cold.pool.reserved_bytes
+
+
+def test_cache_hits_compile_nothing_new(llama):
+    """Adopting cached blocks reuses the executables cold serving compiled:
+    warm runs after cold ones, at temperature 0 and 0.8, add nothing to
+    any serving jit cache."""
+    from repro.analysis import trace_audit
+
+    model, params = llama
+    vocab = model.config.vocab_size
+    for temperature in (0.0, 0.8):
+        _system_prompt_sched(model, params, prefix_cache=False).run(
+            _system_prompt_trace(vocab, temperature))
+    jits = trace_audit.serving_jits()
+    before = trace_audit._cache_sizes(jits)
+    for temperature in (0.0, 0.8):
+        warm = _system_prompt_sched(model, params, prefix_cache=True)
+        warm.run(_system_prompt_trace(vocab, temperature))
+        assert warm.n_prefix_hits > 0
+    grew = {name: (before[name], n)
+            for name, n in trace_audit._cache_sizes(jits).items()
+            if n != before[name]}
+    assert not grew, f"cache hits compiled new executables: {grew}"
+
+
 def test_preemption_replay_stays_identical_under_tight_blocks(llama):
     """Tight pool: decode growth forces preemption, and preempted warm
     requests replay through a trie that may hold their OWN pre-preemption

@@ -315,6 +315,47 @@ def test_mixed_profile_poisson_trace_tight_pool(llama):
         )
 
 
+def test_mixed_profile_trace_reorders_beams_in_place(llama):
+    """A greedy/beam/contrastive backlog through the chunked+paged pool
+    with room for every group: each group is admitted once, the beam
+    reorder runs only as block-table permutation (never the device
+    gather), the pool's reserved bytes never change, and every beam
+    group's score matches its batch engine's."""
+    model, params = llama
+    from repro.launch import serve
+
+    reqs = serve.poisson_trace(
+        serve.data_mod.PAPER_PROFILES["seamless_s2t"], 6, pad_to=PAD_TO,
+        max_new_cap=10, vocab_size=model.config.vocab_size,
+        arrival_rate=0.0, seed=3,
+    )
+    serve.apply_profile_mix(reqs, "greedy,beam,contrastive", n_beams=2,
+                            beam_eos_id=2, guidance=2.0)
+    sched = Scheduler(model, params, slots=4, pad_to=PAD_TO, max_new_cap=10,
+                      paged=True, block_size=4, num_blocks=22,
+                      chunked=True, prefill_budget=4)
+    reserved = sched.pool.reserved_bytes
+    done = sched.run(reqs)
+    assert len(done) == len(reqs)
+    assert sched.n_preemptions == 0
+    assert sched.n_group_admissions == 4  # two beam and two CFG groups
+    assert sched.n_block_permutes >= 1, "beam reorder never ran"
+    assert sched.n_cache_reorders == 0, "paged beam used the device gather"
+    assert sched.pool.reserved_bytes == reserved  # no new KV device buffers
+    beams = [r for r in reqs if isinstance(r.profile, profiles.BeamProfile)]
+    assert beams
+    for r in beams:
+        got = next(d for d in done if d.rid == r.rid)
+        ref = engine.generate_beam(
+            model, params, n_beams=2, eos_id=2, max_new_tokens=r.max_new,
+            prompt_tokens=jnp.asarray(np.asarray(r.prompt, np.int32)[None]),
+        )
+        np.testing.assert_array_equal(
+            np.asarray(got.tokens),
+            np.asarray(ref["tokens"])[0][: len(got.tokens)])
+        assert got.score == pytest.approx(float(ref["scores"][0]), abs=1e-4)
+
+
 # ------------------------------------------------- group admission gates
 def test_group_feasibility_checks(llama):
     model, params = llama

@@ -57,7 +57,8 @@ def test_ssd_chunked_equals_sequential(t, chunk, seed):
 )
 def test_lse_combine_split_invariance(s, nsplit, seed):
     """Flash-decode partials combine to the same result for ANY split of
-    the KV cache (the property that makes sequence-parallel decode exact)."""
+    the KV cache (the property that makes the tiled paged decode walk
+    exact)."""
     b, hq, hkv, d = 2, 4, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (b, hq, d))

@@ -130,6 +130,50 @@ def test_router_sampled_identical_to_single_pool(llama, paged, temperature,
     assert all(len(t) >= 1 for t in got.values())
 
 
+def _backlog_steps(model, params, n_replicas):
+    """Serve one all-at-t=0 paged backlog; return the router's tokens and
+    the decode steps of its busiest replica (the fleet's wall in steps)."""
+    router, tokens = _router_tokens(
+        model, params, _requests(model.config, 16, seed=5),
+        n_replicas=n_replicas, **_pool_kwargs(True))
+    return tokens, max(s.n_decode_steps for s in router.replicas)
+
+
+def test_router_backlog_splits_steps_across_replicas(llama):
+    """Fleet scaling in steps: on a backlog, two replicas finish the same
+    tokens with the busiest one running at most 1/1.6 of one replica's
+    steps."""
+    model, params = llama
+    tokens_one, steps_one = _backlog_steps(model, params, 1)
+    tokens_two, steps_two = _backlog_steps(model, params, 2)
+    assert tokens_two == tokens_one and len(tokens_one) == 16
+    assert steps_one >= 1.6 * steps_two, (steps_one, steps_two)
+
+
+def test_router_replays_the_single_pool_executables(llama):
+    """Replicas of one geometry reuse the single pool's compiled steps: a
+    router run after a single-pool run compiles nothing."""
+    from repro.analysis import trace_audit
+
+    model, params = llama
+    cfg = model.config
+    pool = _pool_kwargs(True)
+    for temperature in (0.0, 0.8):
+        _single_tokens(model, params, _requests(cfg, 6, seed=2,
+                                                temperature=temperature),
+                       **pool)
+    jits = trace_audit.serving_jits()
+    before = trace_audit._cache_sizes(jits)
+    for temperature in (0.0, 0.8):
+        _router_tokens(model, params, _requests(cfg, 6, seed=4,
+                                                temperature=temperature),
+                       n_replicas=2, **pool)
+    grew = {name: (before[name], n)
+            for name, n in trace_audit._cache_sizes(jits).items()
+            if n != before[name]}
+    assert not grew, f"the router compiled new executables: {grew}"
+
+
 def test_router_base_key_must_be_shared(llama):
     """Negative control: DIFFERENT base keys change sampled tokens, so
     identity above genuinely exercises the shared-key invariant. The

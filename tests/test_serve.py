@@ -126,6 +126,29 @@ def test_fixed_policy_matches_continuous_greedy(llama):
     assert steps["fixed"] >= steps["continuous"]
 
 
+def test_continuous_keeps_more_slots_busy_than_fixed(llama):
+    """On a backlog of uneven output lengths, continuous admission refills
+    the slots that run-to-completion batches leave idle: a higher mean
+    occupancy and fewer decode steps for the same tokens."""
+    model, params = llama
+    rng = np.random.default_rng(4)
+    reqs = _requests(model.config, 12, rng, [3, 16, 6, 12])
+    occupancy, steps, outs = {}, {}, {}
+    for policy in ("continuous", "fixed"):
+        sched = Scheduler(
+            model, params, slots=4, pad_to=PAD_TO, max_new_cap=16,
+            policy=policy,
+        )
+        done = sched.run([dataclasses.replace(r, tokens=[]) for r in reqs])
+        assert len(done) == len(reqs)
+        outs[policy] = {d.rid: list(d.tokens) for d in done}
+        occupancy[policy] = sched.mean_occupancy
+        steps[policy] = sched.n_decode_steps
+    assert outs["continuous"] == outs["fixed"]
+    assert occupancy["continuous"] > occupancy["fixed"]
+    assert steps["continuous"] < steps["fixed"]
+
+
 def test_scheduler_eos_eviction_matches_generate(llama):
     """EOS-finished slots are evicted and refilled mid-flight; outputs
     still match generate's (EOS-padded) contract request by request."""

@@ -1,19 +1,10 @@
-"""Sharding-rule unit tests (single device: specs only) plus one real
-multi-device dry-run smoke test in a subprocess (512 host devices)."""
-import os
-import subprocess
-import sys
-
+"""Sharding-rule unit tests (single device: specs only)."""
 import jax
-import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.configs import CONFIGS, SMOKE_CONFIGS, get_shape
+from repro.configs import CONFIGS
 from repro.distributed import sharding as sh
-from repro.launch.mesh import make_debug_mesh
 from repro.models import get_model
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _mesh():
@@ -117,17 +108,3 @@ def test_opt_state_specs_follow_params():
     specs = sh.opt_state_specs(cfg, os_, MESH16)
     assert specs.step == P()
     assert specs.mu["scanned"]["ffn"]["w1"]["w"] == P(None, None, "model")
-
-
-@pytest.mark.slow
-def test_dryrun_subprocess_production_mesh():
-    """The real thing: 512 host devices, production mesh, lower+compile."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.launch.dryrun",
-         "--arch", "mamba2-130m", "--shape", "long_500k", "--multi-pod"],
-        capture_output=True, text=True, env=env, timeout=560,
-    )
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "FAILED=0" in r.stdout

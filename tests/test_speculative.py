@@ -127,6 +127,45 @@ def test_speculative_fewer_steps_than_plain(llama):
     assert steps["spec"] < steps["plain"]
 
 
+def test_speculative_chunked_pool_commits_windows_in_place(llama):
+    """The same greedy trace through the chunked+paged pool, plain and as
+    draft/verify windows: identical tokens, more than 1.5 tokens committed
+    per speculative slot-step, strictly fewer pool steps, and no new KV
+    device bytes (drafts write the static pool; a rejection is a host-side
+    rewind)."""
+    model, params = llama
+    from repro.launch import serve
+
+    def trace(speculative):
+        reqs = serve.poisson_trace(
+            serve.data_mod.PAPER_PROFILES["seamless_s2t"], 8, pad_to=16,
+            max_new_cap=32, vocab_size=model.config.vocab_size,
+            arrival_rate=0.0, seed=0,
+        )
+        if speculative:
+            serve.apply_profile_mix(reqs, "speculative", exit_layer=1,
+                                    n_draft=4)
+        return reqs
+
+    outs, steps = {}, {}
+    for tag in ("plain", "spec"):
+        sched = Scheduler(
+            model, params, slots=4, pad_to=16, max_new_cap=32, paged=True,
+            block_size=16, num_blocks=14, chunked=True, prefill_budget=4,
+        )
+        reserved = sched.pool.reserved_bytes
+        done = sched.run(trace(tag == "spec"))
+        assert len(done) == 8
+        assert sched.pool.reserved_bytes == reserved
+        outs[tag] = {d.rid: list(d.tokens) for d in done}
+        steps[tag] = sched.n_decode_steps
+        if tag == "spec":
+            assert sched.n_spec_steps >= 1
+            assert sched.n_spec_committed > 1.5 * sched.n_spec_slot_steps
+    assert outs["spec"] == outs["plain"]
+    assert steps["spec"] < steps["plain"]
+
+
 def test_speculative_stochastic_identity_and_preemption_replay(llama):
     """Sampling at temperature > 0: committed tokens come from full-model
     logits under the per-(rid, stream, step) fold_in keys, so the

@@ -7,10 +7,14 @@ GROUPS for DP x TP placement), the --mix-classes trace generator, and a
 TP=1 in-process run that must be token-identical to the plain scheduler
 (the dispatch seam itself, with no sharding in play).
 
-Slow tier: one real 2-device subprocess (forced host devices) asserting
+Subprocess tier (forced host devices): a 2-device run asserting
 sharded-vs-single-device token parity on the toy config plus the
-physically-split KV pool (per-device reserved bytes ~ 1/TP).
+physically-split KV pool (per-device reserved bytes ~ 1/TP), the packed
+mixed step on a 2-device mesh, and one 4-device run that holds TP=2 to
+one device's tokens, compiles and host syncs across the plain paged,
+speculative and prefix-cache paths and as DP x TP replicas.
 """
+import json
 import os
 import subprocess
 import sys
@@ -379,3 +383,139 @@ def test_tp2_packed_mixed_step_matches_one_device():
     )
     assert r.returncode == 0, r.stdout + r.stderr
     assert "TP2_MIXED_OK" in r.stdout
+
+
+# --------------------------------------------------------------------------
+# the rest of the serving surface on 4 forced host devices: TP=2 beside one
+# device on the plain paged, speculative and prefix-cache paths, compile
+# and host-sync parity, and two TP=2 replicas on disjoint submeshes
+# --------------------------------------------------------------------------
+
+_TP2_SURFACE_SCRIPT = """
+import json
+import jax
+assert jax.device_count() == 4, jax.device_count()
+from repro.analysis import trace_audit
+from repro.configs import SMOKE_CONFIGS
+from repro.distributed import sharding
+from repro.launch import serve
+from repro.models import get_model
+from repro.training import data as data_mod
+
+cfg = SMOKE_CONFIGS["llama3.2-1b"].replace(dtype="float32")
+model = get_model(cfg)
+params = model.init(jax.random.PRNGKey(0))
+prof = data_mod.PAPER_PROFILES["seamless_s2t"]
+N = 8
+CHUNKED = dict(slots=4, pad_to=16, max_new_cap=16, block_size=16,
+               num_blocks=14, chunked=True, prefill_budget=4)
+PAGED = dict(slots=4, pad_to=16, max_new_cap=16, block_size=16,
+             num_blocks=14)
+PREFIX = dict(slots=4, pad_to=24, max_new_cap=8, block_size=4, num_blocks=48,
+              chunked=True, prefill_budget=8, prefix_cache=True)
+
+
+def trace(temperature, seed=0):
+    return serve.poisson_trace(
+        prof, N, pad_to=16, max_new_cap=16, vocab_size=cfg.vocab_size,
+        arrival_rate=0.0, seed=seed, temperature=temperature,
+        top_p=0.9 if temperature > 0 else 1.0)
+
+
+def spec_trace():
+    return serve.mix_class_trace(
+        prof, N, pad_to=16, max_new_cap=16, vocab_size=cfg.vocab_size,
+        arrival_rate=0.0, classes=("greedy", "speculative"), seed=0,
+        temperature=0.8)
+
+
+def prefix_trace(temperature):
+    return serve.shared_prefix_trace(
+        N, n_prefixes=2, prefix_len=16, pad_to=24, max_new_cap=8,
+        vocab_size=cfg.vocab_size, arrival_rate=0.0, seed=0,
+        temperature=temperature, top_p=0.9 if temperature > 0 else 1.0)
+
+
+def run(reqs, tp=None, replicas=None, **geometry):
+    m, done = serve.run_scheduler(
+        model, params, reqs, policy="continuous", seed=0, paged=True, tp=tp,
+        replicas=replicas, return_requests=True, **geometry)
+    return m, {r.rid: list(r.tokens) for r in done}
+
+
+def same(got, want):
+    return got == want and len(want) == N
+
+
+gates = {}
+gates["paged_t0.0"] = same(run(trace(0.0), tp=2, **PAGED)[1],
+                           run(trace(0.0), **PAGED)[1])
+gates["speculative_t0.8"] = same(run(spec_trace(), tp=2, **CHUNKED)[1],
+                                 run(spec_trace(), **CHUNKED)[1])
+for t in (0.0, 0.8):
+    m_one, want = run(prefix_trace(t), **PREFIX)
+    m_tp, got = run(prefix_trace(t), tp=2, **PREFIX)
+    gates[f"prefix_t{t}"] = (same(got, want) and m_tp["prefix_hits"]
+                             == m_one["prefix_hits"] > 0)
+
+run(trace(0.8), tp=2, **CHUNKED)
+jits = trace_audit.serving_jits()
+before = trace_audit._cache_sizes(jits)
+run(trace(0.8, seed=1), tp=2, **CHUNKED)
+gates["no_recompiles"] = trace_audit._cache_sizes(jits) == before
+
+real_get, counts = jax.device_get, [0]
+
+
+def counting_get(x):
+    counts[0] += 1
+    return real_get(x)
+
+
+jax.device_get = counting_get
+try:
+    m_one, _ = run(trace(0.0), **CHUNKED)
+    syncs_one, counts[0] = counts[0], 0
+    m_tp, _ = run(trace(0.0), tp=2, **CHUNKED)
+    syncs_tp = counts[0]
+finally:
+    jax.device_get = real_get
+gates["sync_parity"] = (syncs_tp == syncs_one > 0
+                        and m_tp["decode_steps"] == m_one["decode_steps"])
+
+groups = sharding.replica_devices(2, group_size=2)
+flat = [d for g in groups for d in g]
+for t in (0.0, 0.8):
+    want = run(trace(t), **CHUNKED)[1]
+    got = run(trace(t), tp=2, replicas=2, **CHUNKED)[1]
+    gates[f"dp_x_tp_t{t}"] = len(set(flat)) == len(flat) == 4 and same(got, want)
+print("TP2_GATES " + json.dumps(gates))
+"""
+
+TP2_GATES = ["paged_t0.0", "speculative_t0.8", "prefix_t0.0", "prefix_t0.8",
+             "no_recompiles", "sync_parity", "dp_x_tp_t0.0", "dp_x_tp_t0.8"]
+
+
+@pytest.fixture(scope="module")
+def tp2_gates():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    r = subprocess.run(
+        [sys.executable, "-c", _TP2_SURFACE_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=560,
+    )
+    assert r.returncode == 0, r.stdout + r.stderr
+    line = next(x for x in r.stdout.splitlines() if x.startswith("TP2_GATES "))
+    return json.loads(line.split(" ", 1)[1])
+
+
+@pytest.mark.parametrize("gate", TP2_GATES)
+def test_tp2_serves_like_one_device(tp2_gates, gate):
+    """A TP=2 pool serves what one device serves on every path: plain
+    paged decode, speculative windows under sampling, prefix-cache hits
+    (the same hit count), a second trace that compiles nothing, the same
+    host syncs and steps, and as two replicas on disjoint submeshes (DP x
+    TP)."""
+    assert tp2_gates[gate], tp2_gates
